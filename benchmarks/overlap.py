@@ -29,18 +29,15 @@ communication stays below the staged baseline.
 """
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
 import textwrap
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from benchmarks.common import run_cpu_workers
 
 _DIST_CODE = textwrap.dedent("""
     import time
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.configs import get_config
     from repro.core import DistributedOptimizer, ExchangeConfig
     from repro.data import make_pipeline
@@ -118,10 +115,10 @@ _DIST_CODE = textwrap.dedent("""
             if with_state:
                 return jax.jit(shard_map(
                     fn, mesh=mesh, in_specs=(P(), bshard, P(axis)),
-                    out_specs=(P(), P(axis)), check_rep=False))
+                    out_specs=(P(), P(axis)), check_vma=False))
             return jax.jit(shard_map(
                 fn, mesh=mesh, in_specs=(P(), bshard),
-                out_specs=P(), check_rep=False))
+                out_specs=P(), check_vma=False))
 
         # collective-free floor: backward + accumulate + densify
         plan0 = opt_probe.plan(g_abs)
@@ -169,13 +166,13 @@ _DIST_CODE = textwrap.dedent("""
     for name, overlap in (('fused', False), ('overlap', True)):
         opt = make_opt('identity', 'jax', overlap, ('data',))
         sm = jax.jit(shard_map(opt.exchange, mesh=mesh, in_specs=(P(),),
-                               out_specs=P(), check_rep=False))
+                               out_specs=P(), check_vma=False))
         legacy[name] = timed(sm, grads)
         if name == 'fused':
             plan = opt.plan(grads)
             acc = jax.jit(shard_map(plan.accumulate_tree, mesh=mesh,
                                     in_specs=(P(),), out_specs=P(),
-                                    check_rep=False))
+                                    check_vma=False))
             legacy['compute_only'] = timed(acc, grads)
             print('N_STAGES', plan.schedule.n_stages)
     print('COMPUTE_US', legacy['compute_only'])
@@ -185,58 +182,50 @@ _DIST_CODE = textwrap.dedent("""
 
 
 def run(emit):
-    env = dict(os.environ,
-               XLA_FLAGS="--xla_force_host_platform_device_count=8",
-               PYTHONPATH=os.path.join(REPO, "src"))
-    res = subprocess.run([sys.executable, "-c", _DIST_CODE], env=env,
-                         capture_output=True, text=True, timeout=1500)
-    if res.returncode != 0:
-        emit("overlap_error", 0.0, res.stderr[-120:].replace(
-            ",", ";").replace("\n", "|"))
-        return
+    out = run_cpu_workers(_DIST_CODE, timeout=1500)
 
     # per-config end-to-end rows: compute floor, three overlap modes,
     # and the exposed-comm deltas the acceptance contract keys on
-    for line in res.stdout.splitlines():
+    for line in out.splitlines():
         if not line.startswith("TAG "):
             continue
         f = line.split()
         tag = f[1]
         comp, fused, staged, bwd = (float(f[3]), float(f[5]),
                                     float(f[7]), float(f[9]))
-        emit(f"overlap_step_compute_{tag}_P8", comp,
+        emit(f"overlap_step_compute_{tag}_cpuP8", comp,
              "grad+accumulate_no_collectives")
-        emit(f"overlap_step_fused_{tag}_P8", fused, "end_to_end")
-        emit(f"overlap_step_staged_{tag}_P8", staged, "end_to_end")
-        emit(f"overlap_step_backward_{tag}_P8", bwd,
+        emit(f"overlap_step_fused_{tag}_cpuP8", fused, "end_to_end")
+        emit(f"overlap_step_staged_{tag}_cpuP8", staged, "end_to_end")
+        emit(f"overlap_step_backward_{tag}_cpuP8", bwd,
              "end_to_end_wait_free")
         ex_f = max(fused - comp, 0.0)
         ex_s = max(staged - comp, 0.0)
         ex_b = max(bwd - comp, 0.0)
-        emit(f"overlap_exposed_comm_fused_{tag}_P8", ex_f,
+        emit(f"overlap_exposed_comm_fused_{tag}_cpuP8", ex_f,
              "step_minus_compute")
-        emit(f"overlap_exposed_comm_staged_{tag}_P8", ex_s,
+        emit(f"overlap_exposed_comm_staged_{tag}_cpuP8", ex_s,
              "step_minus_compute")
-        emit(f"overlap_exposed_comm_backward_{tag}_P8", ex_b,
+        emit(f"overlap_exposed_comm_backward_{tag}_cpuP8", ex_b,
              f"step_minus_compute_below_staged={ex_b < ex_s}")
 
     def grab(tag):
-        return float(res.stdout.split(tag)[1].split()[0])
+        return float(out.split(tag)[1].split()[0])
 
     # legacy exchange-only rows (identity): perf-trajectory continuity
     comp, fused, over = (grab("COMPUTE_US"), grab("FUSED_US"),
                          grab("OVERLAP_US"))
     n_stages = int(grab("N_STAGES"))
-    emit("overlap_compute_only_P8", comp,
+    emit("overlap_compute_only_cpuP8", comp,
          "accumulate+densify_no_collectives")
-    emit("overlap_exchange_fused_P8", fused,
+    emit("overlap_exchange_fused_cpuP8", fused,
          f"serial_schedule_{n_stages}stages")
-    emit("overlap_exchange_staged_P8", over,
+    emit("overlap_exchange_staged_cpuP8", over,
          f"launch_all_then_unpack_{n_stages}stages")
-    emit("overlap_exposed_comm_fused_P8", max(fused - comp, 0.0),
+    emit("overlap_exposed_comm_fused_cpuP8", max(fused - comp, 0.0),
          "exchange_minus_compute")
-    emit("overlap_exposed_comm_staged_P8", max(over - comp, 0.0),
+    emit("overlap_exposed_comm_staged_cpuP8", max(over - comp, 0.0),
          "exchange_minus_compute")
     hidden = (fused - over) / max(fused - comp, 1e-9)
-    emit("overlap_hidden_fraction_P8", 0.0,
+    emit("overlap_hidden_fraction_cpuP8", 0.0,
          f"{hidden:.3f}_of_exposed_comm_hidden_cpu_smem")

@@ -7,6 +7,12 @@
 # ``--json`` additionally writes one machine-readable ``BENCH_<name>.json``
 # per module (the perf-trajectory artifact CI uploads).
 #
+# Modules that emulate workers run them in a child pinned to the CPU
+# (rows named ``cpuP8``), so a child never contends for an accelerator
+# this process holds; this file itself opens no backend before the
+# modules run (provenance is stamped after them).  A failed module or
+# child makes the run exit non-zero.
+#
 # Modules (paper artifact -> module):
 #   Fig 3 / Fig 5 space : accumulation_memory
 #   Fig 5 time          : accumulation_time
@@ -19,9 +25,11 @@
 #   §Serving            : serving_load (Poisson TTFT/TPOT + hot swap)
 import argparse
 import json
+import os
 import subprocess
 import sys
 import time
+import traceback
 
 
 def provenance(timestamp=None):
@@ -46,7 +54,7 @@ def provenance(timestamp=None):
     return prov
 
 
-def main() -> None:
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma-separated module substrings to run")
@@ -81,9 +89,11 @@ def main() -> None:
         modules = [(n, m) for n, m in modules
                    if any(k in n for k in keys)]
 
+    from repro.launch.cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
 
-    prov = provenance(args.timestamp) if args.json else None
+    results, failed = [], []
     for name, mod in modules:
         rows = []
 
@@ -95,17 +105,30 @@ def main() -> None:
                           "derived": derived})
 
         t0 = time.perf_counter()
-        mod.run(emit)
+        try:
+            mod.run(emit)
+        except Exception:
+            print(f"benchmark {name} failed:", file=sys.stderr)
+            traceback.print_exc()
+            failed.append(name)
+            continue
         wall_s = time.perf_counter() - t0
         emit(f"_module_{name}_wall_s", wall_s * 1e6, "total")
-        if args.json:
-            import os
+        results.append((name, wall_s, rows))
+    if args.json:
+        # stamped after the modules: provenance opens the backend
+        prov = provenance(args.timestamp)
+        for name, wall_s, rows in results:
             path = os.path.join(args.json_dir, f"BENCH_{name}.json")
             with open(path, "w") as f:
                 json.dump({"module": name, "wall_s": wall_s,
                            "provenance": prov, "rows": rows},
                           f, indent=2)
+    if failed:
+        print(f"failed: {','.join(failed)}", file=sys.stderr)
+        return 1
+    return 0
 
 
 if __name__ == '__main__':
-    main()
+    sys.exit(main())

@@ -21,12 +21,9 @@ transformer-big on 8 emulated CPU workers:
 """
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
 import textwrap
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+from benchmarks.common import run_cpu_workers
 
 _TUNE_CODE = textwrap.dedent("""
     import jax, jax.numpy as jnp
@@ -81,37 +78,30 @@ _TUNE_CODE = textwrap.dedent("""
 
 
 def run(emit):
-    env = dict(os.environ,
-               XLA_FLAGS="--xla_force_host_platform_device_count=8",
-               PYTHONPATH=os.path.join(REPO, "src"))
-    res = subprocess.run([sys.executable, "-c", _TUNE_CODE], env=env,
-                         capture_output=True, text=True, timeout=1800)
-    if res.returncode != 0:
-        emit("tune_error", 0.0, res.stderr[-120:].replace(
-            ",", ";").replace("\n", "|"))
-        return
+    out = run_cpu_workers(_TUNE_CODE, timeout=1800)
 
     def grab(tag):
-        return res.stdout.split(tag)[1].split()[0]
+        return out.split(tag)[1].split()[0]
 
     n_ok, n_all = int(grab("N_OK")), float(grab("N_ALL"))
     rho = float(grab("SPEARMAN"))
-    sel_rank = int(res.stdout.split("SELECTED")[1].split("RANK")[1]
+    sel_rank = int(out.split("SELECTED")[1].split("RANK")[1]
                    .split()[0])
-    ana_rank = int(res.stdout.split("ANALYTIC_BEST")[1].split("RANK")[1]
+    ana_rank = int(out.split("ANALYTIC_BEST")[1].split("RANK")[1]
                    .split()[0])
-    emit("tune_space_measured_P8", n_ok, f"of_{int(n_all)}_candidates")
-    emit("tune_rank_spearman_P8", 0.0, f"rho={rho:.3f}_analytic_vs_measured")
-    emit("tune_analytic_best_measured_rank_P8", float(ana_rank),
+    emit("tune_space_measured_cpuP8", n_ok, f"of_{int(n_all)}_candidates")
+    emit("tune_rank_spearman_cpuP8", 0.0,
+         f"rho={rho:.3f}_analytic_vs_measured")
+    emit("tune_analytic_best_measured_rank_cpuP8", float(ana_rank),
          "rank_of_analytic_no1_in_measured_order")
-    emit("tune_selected_measured_rank_P8", float(sel_rank),
+    emit("tune_selected_measured_rank_cpuP8", float(sel_rank),
          f"measured_best_of_analytic_top5_in_top2={sel_rank <= 2}")
-    for line in res.stdout.splitlines():
+    for line in out.splitlines():
         if not line.startswith("CAND "):
             continue
         f = line.split()
         ana, meas, pred_us, meas_us = f[1], f[2], f[3], f[4]
         label = f[5].replace(",", ";")
-        emit(f"tune_cand_{label}_P8", float(meas_us),
+        emit(f"tune_cand_{label}_cpuP8", float(meas_us),
              f"predicted_us={pred_us}_analytic_rank={ana}"
              f"_measured_rank={meas}")

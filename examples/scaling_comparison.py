@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.configs import get_config
 from repro.core import DistributedOptimizer, ExchangeConfig
@@ -84,7 +84,7 @@ def main(argv=None):
         step = shard_map(
             make_train_step(model, opt, sparse_embedding=True),
             mesh=mesh, in_specs=(P(), P(), P("data")),
-            out_specs=(P(), P(), P()), check_rep=False)
+            out_specs=(P(), P(), P()), check_vma=False)
         step = jax.jit(step)
         p, s = params, opt.init(params)
         p, s, m = step(p, s, batch)               # compile

@@ -73,10 +73,10 @@ def main():
     batch_per_host = args.batch_per_worker
     if axis is not None:
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         mesh = Mesh(np.array(jax.devices()), ("data",))
         step = shard_map(step, mesh=mesh, in_specs=(P(), P(), P("data")),
-                         out_specs=(P(), P(), P()), check_rep=False)
+                         out_specs=(P(), P(), P()), check_vma=False)
         batch_per_host *= n_dev
         print(f"horovod mode: {n_dev} workers")
 

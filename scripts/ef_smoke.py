@@ -20,6 +20,8 @@ ap.add_argument("--tolerance", type=float, default=0.15,
                 help="max |loss_ef - loss_fp32| in nats")
 args = ap.parse_args()
 
+# workers are emulated on host devices: stay off any accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                            f" --xla_force_host_platform_device_count="
                            f"{args.workers}")
@@ -27,7 +29,7 @@ os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
 import jax                                                  # noqa: E402
 import jax.numpy as jnp                                     # noqa: E402
 import numpy as np                                          # noqa: E402
-from jax.experimental.shard_map import shard_map            # noqa: E402
+from jax import shard_map                                   # noqa: E402
 from jax.sharding import Mesh, PartitionSpec as P           # noqa: E402
 
 from repro.configs import get_config                        # noqa: E402
@@ -57,12 +59,12 @@ def final_loss(codec: str, error_feedback: bool) -> float:
         step = shard_map(step, mesh=mesh,
                          in_specs=(P(), P(), P("data"), P("data")),
                          out_specs=(P(), P(), P("data"), P()),
-                         check_rep=False)
+                         check_vma=False)
     else:
         step = shard_map(step, mesh=mesh,
                          in_specs=(P(), P(), P("data")),
                          out_specs=(P(), P(), P()),
-                         check_rep=False)
+                         check_vma=False)
     pipe = make_pipeline(cfg, batch_per_host=2 * n_dev, seq_len=16,
                          task="copy")
     ex_state = None

@@ -46,28 +46,32 @@ def _densify_kernel(idx_ref, val_ref, out_ref, *, block_v: int):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    idx = idx_ref[...]                                   # (BN,)
-    local = idx - vb * block_v                           # position in tile
-    # one-hot (BN, BV): row r lights column local[r] iff it falls in-tile.
-    cols = jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], block_v), 1)
-    onehot = (local[:, None] == cols).astype(val_ref.dtype)
-    # MXU matmul: (BV, BN) @ (BN, BD) -> (BV, BD), accumulated in fp32.
-    out_ref[...] += jax.lax.dot_general(
-        onehot, val_ref[...],
-        dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=out_ref.dtype,
-    )
+    local = idx_ref[...] - vb * block_v                  # (1, BN) in-tile
+    # one-hot (BV, BN): column r lights row local[r] iff it falls in-tile.
+    rows = jax.lax.broadcasted_iota(jnp.int32, (block_v, local.shape[1]), 0)
+    onehot = (rows == local).astype(val_ref.dtype)
+    # MXU matmul: (BV, BN) @ (BN, BD) -> (BV, BD), accumulated in fp32;
+    # f32 rows take the multi-pass HIGHEST contraction so they stay
+    # exact (one default pass would round them to bf16)
+    exact = (jax.lax.Precision.HIGHEST if val_ref.dtype == jnp.float32
+             else None)
+    out_ref[...] += jnp.dot(onehot, val_ref[...], precision=exact,
+                            preferred_element_type=out_ref.dtype)
 
 
 def densify_pallas(indices: jax.Array, values: jax.Array,
                    dense_shape: Tuple[int, ...],
                    block_v: int = DEFAULT_BLOCK_V,
                    block_d: int = DEFAULT_BLOCK_D,
-                   block_n: int = DEFAULT_BLOCK_N,
-                   interpret: bool = True) -> jax.Array:
+                   block_n: int = DEFAULT_BLOCK_N, *,
+                   interpret: bool) -> jax.Array:
     """Raw pallas_call. Requires pre-padded inputs:
     ``len(indices) % block_n == 0``, ``dense_shape`` divisible by
     ``(block_v, block_d)``.  Use ``ops.densify`` for arbitrary shapes.
+
+    The ids travel as one ``(1, n)`` row so each ``(1, block_n)`` block
+    is lane-aligned: a 1-D ``(block_n,)`` int32 block asks Mosaic for a
+    tiling the XLA layout of the operand does not have.
     """
     vocab, d = dense_shape
     n = indices.shape[0]
@@ -79,11 +83,11 @@ def densify_pallas(indices: jax.Array, values: jax.Array,
         functools.partial(_densify_kernel, block_v=block_v),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_n,), lambda i, j, r: (r,)),
+            pl.BlockSpec((1, block_n), lambda i, j, r: (0, r)),
             pl.BlockSpec((block_n, block_d), lambda i, j, r: (r, j)),
         ],
         out_specs=pl.BlockSpec((block_v, block_d), lambda i, j, r: (i, j)),
         out_shape=jax.ShapeDtypeStruct((vocab, d), out_dtype),
         interpret=interpret,
-    )(indices.astype(jnp.int32), values)
+    )(indices.astype(jnp.int32).reshape(1, n), values)
     return out.astype(values.dtype)

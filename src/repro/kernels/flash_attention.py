@@ -85,8 +85,8 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
                            block_q: int = DEFAULT_BLOCK_Q,
                            block_k: int = DEFAULT_BLOCK_K,
                            q_offset: Optional[int] = None,
-                           kv_len: Optional[int] = None,
-                           interpret: bool = True) -> jax.Array:
+                           kv_len: Optional[int] = None, *,
+                           interpret: bool) -> jax.Array:
     """Raw pallas_call over pre-flattened heads.
 
     Shapes: q (BH, Sq, D), k/v (BH, Sk, D); Sq % block_q == 0,

@@ -2,9 +2,12 @@
 
 Handle padding to block multiples, GQA head expansion and layout
 (B, S, H, D) <-> (B*H, S, D); dispatch between the Pallas kernel
-(``impl="pallas"``, interpret-mode on CPU, native on TPU) and the pure-JAX
-oracle-equivalent paths used by the 512-device dry-run
-(``impl="xla"`` / ``impl="xla_chunked"``).
+(``impl="pallas"``) and the pure-JAX oracle-equivalent paths used by the
+512-device dry-run (``impl="xla"`` / ``impl="xla_chunked"``).
+
+Whether a Pallas kernel runs natively or in the interpreter is decided in
+one place, ``pallas_interpret``, from the backend: interpreted on the CPU
+(the test mode), native on the TPU, and an error anywhere else.
 """
 from __future__ import annotations
 
@@ -25,6 +28,20 @@ from repro.kernels.ssd import ssd_pallas
 
 def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
+
+
+def pallas_interpret() -> bool:
+    """``interpret=`` for every Pallas call: True on the CPU, False on
+    the TPU.  Any other backend has neither a Mosaic compiler nor a
+    reason to pay for the interpreter, so it is refused."""
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise NotImplementedError(
+        f"Pallas kernels run natively on TPU or interpreted on CPU; "
+        f"backend {backend!r} has neither — use impl='xla'")
 
 
 # ---------------------------------------------------------------------------
@@ -52,7 +69,8 @@ def densify(indices: jax.Array, values: jax.Array,
     idx = jnp.where((idx >= 0) & (idx < vocab), idx, -1)
     vals = jnp.zeros((np_, dp), values.dtype).at[:n, :d].set(values)
     out = densify_pallas(idx, vals, (vp, dp), block_v=block_v,
-                         block_d=block_d, block_n=block_n)
+                         block_d=block_d, block_n=block_n,
+                         interpret=pallas_interpret())
     return out[:vocab, :d]
 
 
@@ -66,9 +84,8 @@ def quantize_int8(x: jax.Array, impl: str = "pallas"):
 
     ``q = clip(round(x / scale), -127, 127)`` with
     ``scale = absmax(x) / 127``; ``impl="pallas"`` runs the fused
-    scale/round/clip/cast chain as one VPU pass (interpret on CPU),
-    ``impl="xla"`` is the pure-jax fallback.  Dequantise with
-    ``q.astype(f32) * scale``.
+    scale/round/clip/cast chain as one VPU pass, ``impl="xla"`` is the
+    pure-jax fallback.  Dequantise with ``q.astype(f32) * scale``.
     """
     flat = x.reshape(-1).astype(jnp.float32)
     absmax = jnp.max(jnp.abs(flat)) if flat.size else jnp.float32(0)
@@ -76,7 +93,8 @@ def quantize_int8(x: jax.Array, impl: str = "pallas"):
     if impl == "xla":
         q = jnp.clip(jnp.round(flat / scale), -QMAX, QMAX).astype(jnp.int8)
     else:
-        q = quantize_pallas(flat, 1.0 / scale)
+        q = quantize_pallas(flat, 1.0 / scale,
+                            interpret=pallas_interpret())
     return q.reshape(x.shape), scale.reshape(1)
 
 
@@ -104,16 +122,19 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     """Multi-head attention, shapes q (B,Sq,H,D), k/v (B,Sk,Hkv,D) (GQA ok).
 
     impl:
-      pallas       Pallas kernel (interpret on CPU, native on TPU)
+      pallas       Pallas kernel; needs equal q/v head dims
       xla          full-softmax reference (small shapes only)
       xla_chunked  pure-JAX online-softmax scan over kv blocks — the
-                   memory-safe path the 512-device dry-run lowers
+                   memory-safe path the 512-device dry-run lowers; takes
+                   unequal q/v head dims (MLA)
     """
     h = q.shape[2]
     k = _expand_kv(k, h)
     v = _expand_kv(v, h)
     if impl == "pallas" and v.shape[-1] != q.shape[-1]:
-        impl = "xla_chunked"   # mixed head dims (MLA): kernel variant TBD
+        raise ValueError(
+            f"impl='pallas' needs equal q/v head dims, got "
+            f"{q.shape[-1]} and {v.shape[-1]}; ask for 'xla_chunked'")
     if impl == "xla":
         return ref.attention_ref(q, k, v, causal=causal, window=window)
     if impl == "xla_chunked":
@@ -146,7 +167,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     # kv_len masks the padded trailing keys (essential when causal=False)
     out = flash_attention_pallas(qp, kp, vp, causal=causal, window=window,
                                  scale=scale, block_q=bq, block_k=bk,
-                                 q_offset=sk - sq, kv_len=sk)
+                                 q_offset=sk - sq, kv_len=sk,
+                                 interpret=pallas_interpret())
     out = out.reshape(b, h, sqp, d).transpose(0, 2, 1, 3)
     return out[:, :sq]
 
@@ -216,8 +238,8 @@ def ssd(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     x (B, S, H, P), dt (B, S, H), a (H,), b/c (B, S, N).
     Returns (y (B, S, H, P), final_state (B, H, N, P)).
 
-    impl="pallas": VMEM-resident per-chunk tiles (interpret on CPU,
-    native on TPU); impl="xla": sequential-recurrence oracle.
+    impl="pallas": VMEM-resident per-chunk tiles;
+    impl="xla": sequential-recurrence oracle.
     """
     bb, s, h, p = x.shape
     n = b.shape[-1]
@@ -236,6 +258,7 @@ def ssd(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     if impl == "xla":
         y, state = ref.ssd_ref(xf, dtf, af, bf, cf)
     else:
-        y, state = ssd_pallas(xf, dtf, af, bf, cf, chunk)
+        y, state = ssd_pallas(xf, dtf, af, bf, cf, chunk,
+                              interpret=pallas_interpret())
     y = y.reshape(bb, h, sp, p).transpose(0, 2, 1, 3)[:, :s]
     return y, state.reshape(bb, h, n, p)

@@ -33,8 +33,8 @@ def _quantize_kernel(x_ref, inv_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def quantize_pallas(flat: jax.Array, inv_scale: jax.Array,
-                    block_rows: int = DEFAULT_BLOCK_ROWS,
-                    interpret: bool = True) -> jax.Array:
+                    block_rows: int = DEFAULT_BLOCK_ROWS, *,
+                    interpret: bool) -> jax.Array:
     """Quantise a flat f32/bf16 buffer to int8 at ``1/inv_scale``.
 
     Pads to ``(block_rows, 128)`` tile multiples internally; returns the

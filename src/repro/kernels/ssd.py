@@ -37,41 +37,50 @@ def _ssd_kernel(a_ref, dt_ref, x_ref, b_ref, c_ref, y_ref, state_out_ref,
     def _init():
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    a = a_ref[0]                                   # scalar decay (<0)
-    dt = dt_ref[0].astype(jnp.float32)             # (L,)
+    a = a_ref[pl.program_id(0)]                    # scalar decay (<0)
+    dt = dt_ref[0].astype(jnp.float32)             # (L, 1) column
     x = x_ref[0].astype(jnp.float32)               # (L, P)
     bb = b_ref[0].astype(jnp.float32)              # (L, N)
     cc = c_ref[0].astype(jnp.float32)              # (L, N)
 
-    da = dt * a
-    cum = jnp.cumsum(da)                           # (L,) <= 0
+    li = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    lj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    causal = li >= lj
+    # inclusive prefix sum as a lower-triangular matmul (Mosaic has no
+    # cumsum); HIGHEST keeps it exact in f32
+    cum = jax.lax.dot_general(
+        causal.astype(jnp.float32), dt * a, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)        # (L, 1) <= 0
     pos = jnp.exp(cum)
     neg = jnp.exp(jnp.minimum(-cum, CLIP))
 
     scores = jax.lax.dot_general(cc, bb, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-    li = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    lj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    masked = jnp.where(li >= lj, scores, 0.0)
+    masked = jnp.where(causal, scores, 0.0)
 
-    bj = (neg * dt)[:, None] * x                   # (L, P)
+    bj = (neg * dt) * x                            # (L, P)
     acc = jax.lax.dot_general(masked, bj, (((1,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)
-    y = pos[:, None] * acc
+    y = pos * acc
     # exact diagonal correction (clip-robust self contribution)
-    diag = jnp.sum(cc * bb, axis=1)                # (L,)
-    y = y + ((1.0 - pos * neg) * dt * diag)[:, None] * x
+    diag = jnp.sum(cc * bb, axis=1, keepdims=True)  # (L, 1)
+    y = y + ((1.0 - pos * neg) * dt * diag) * x
     # inter-chunk: contribution of the carried state
-    y = y + pos[:, None] * jax.lax.dot_general(
+    y = y + pos * jax.lax.dot_general(
         cc, state_ref[...], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
     y_ref[0] = y.astype(y_ref.dtype)
 
     # state update: S' = exp(cum_L) S + sum_j exp(cum_L - cum_j) dt_j B_j x_j
-    w = dt * jnp.exp(cum[-1] - cum)                # (L,)
-    state_ref[...] = (jnp.exp(cum[-1]) * state_ref[...]
+    # cum_L is formed as a (1, P) row: Mosaic cannot broadcast a (1, 1)
+    # value across both sublanes and lanes
+    last = jnp.sum(jnp.broadcast_to(dt * a, x.shape), axis=0,
+                   keepdims=True)                  # (1, P), all cum_L
+    wx = jnp.exp(last - cum) * (dt * x)            # (L, P)
+    state_ref[...] = (jnp.exp(last) * state_ref[...]
                       + jax.lax.dot_general(
-                          bb * w[:, None], x, (((0,), (0,)), ((), ())),
+                          bb, wx, (((0,), (0,)), ((), ())),
                           preferred_element_type=jnp.float32))
 
     @pl.when(ci == n_chunks - 1)
@@ -80,8 +89,8 @@ def _ssd_kernel(a_ref, dt_ref, x_ref, b_ref, c_ref, y_ref, state_out_ref,
 
 
 def ssd_pallas(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
-               c: jax.Array, chunk: int,
-               interpret: bool = True) -> Tuple[jax.Array, jax.Array]:
+               c: jax.Array, chunk: int, *,
+               interpret: bool) -> Tuple[jax.Array, jax.Array]:
     """Raw pallas_call.
 
     x (BH, S, P), dt (BH, S), a (BH,), b/c (BH, S, N); S % chunk == 0.
@@ -98,8 +107,10 @@ def ssd_pallas(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
         functools.partial(_ssd_kernel, chunk=chunk, n_chunks=nc),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1,), lambda i, j: (i,)),
-            pl.BlockSpec((1, chunk), lambda i, j: (i, j)),
+            # per-(batch, head) decays live whole in SMEM: a rank-1
+            # (1,) VMEM block is not a legal TPU tile
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, chunk, 1), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, chunk, p), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, chunk, n), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, chunk, n), lambda i, j: (i, j, 0)),
@@ -114,5 +125,5 @@ def ssd_pallas(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
         ],
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
         interpret=interpret,
-    )(a.astype(jnp.float32), dt, x, b, c)
+    )(a.astype(jnp.float32), dt.reshape(bh, s, 1), x, b, c)
     return y, state

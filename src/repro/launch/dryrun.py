@@ -303,7 +303,7 @@ def audit_exchange_plan(arch: str = "transformer-big", n_workers: int = 8,
     """
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from repro.optim import adamw as adamw_opt
 
@@ -360,7 +360,7 @@ def audit_exchange_plan(arch: str = "transformer-big", n_workers: int = 8,
             ex = shard_map(z_fn, mesh=mesh,
                            in_specs=(P(), P(), zspec, P(axis_name)),
                            out_specs=(P(), zspec, P(axis_name)),
-                           check_rep=False)
+                           check_vma=False)
             lower_args = (grads, params, z0, state0)
         else:
             def z_fn(g, p_, z):
@@ -369,7 +369,7 @@ def audit_exchange_plan(arch: str = "transformer-big", n_workers: int = 8,
 
             ex = shard_map(z_fn, mesh=mesh,
                            in_specs=(P(), P(), zspec),
-                           out_specs=(P(), zspec), check_rep=False)
+                           out_specs=(P(), zspec), check_vma=False)
             lower_args = (grads, params, z0)
     elif plan.config.overlap_backward:
         from repro.training.gradients import wait_free_grad_exchange
@@ -384,7 +384,7 @@ def audit_exchange_plan(arch: str = "transformer-big", n_workers: int = 8,
 
             ex = shard_map(wf_fn, mesh=mesh,
                            in_specs=(P(), P(), P(axis_name)),
-                           out_specs=(P(), P(axis_name)), check_rep=False)
+                           out_specs=(P(), P(axis_name)), check_vma=False)
             lower_args = (params, batch, state0)
         else:
             def wf_fn(p_, b_):
@@ -392,7 +392,7 @@ def audit_exchange_plan(arch: str = "transformer-big", n_workers: int = 8,
                     model, opt, p_, b_, sparse_embedding=True)[0]
 
             ex = shard_map(wf_fn, mesh=mesh, in_specs=(P(), P()),
-                           out_specs=P(), check_rep=False)
+                           out_specs=P(), check_vma=False)
             lower_args = (params, batch)
     elif plan.config.codec_obj.stateful:
         state0 = plan.init_state(n_workers=n_workers)
@@ -402,11 +402,11 @@ def audit_exchange_plan(arch: str = "transformer-big", n_workers: int = 8,
 
         ex = shard_map(ex_fn, mesh=mesh,
                        in_specs=(P(), P(axis_name)),
-                       out_specs=(P(), P(axis_name)), check_rep=False)
+                       out_specs=(P(), P(axis_name)), check_vma=False)
         lower_args = (grads, state0)
     else:
         ex = shard_map(opt.exchange, mesh=mesh, in_specs=(P(),),
-                       out_specs=P(), check_rep=False)
+                       out_specs=P(), check_vma=False)
         lower_args = (grads,)
     hlo = jax.jit(ex).lower(*lower_args).compile().as_text()
 

@@ -62,27 +62,73 @@ def parse_computations(hlo: str) -> Dict[str, List[str]]:
     return comps
 
 
+_NAME_EQ_RE = re.compile(r"%?[\w\.\-]+\s*=\s*")
+_ARRAY_TYPE_RE = re.compile(r"[a-z0-9]+\[[0-9,]*\](?:{[^}]*})?")
+
+
+def _result_and_opcode(line: str) -> Tuple[str, str]:
+    """("bf16[8,128]{1,0}", "all-reduce") from "%x = bf16[8,128]{1,0}
+    all-reduce(...)".  Tuple results are matched by balanced parentheses:
+    TPU layouts carry their own, as in ``{0:T(1024)(128)}``."""
+    m = _NAME_EQ_RE.match(line)
+    if not m:
+        return "", ""
+    rest = line[m.end():]
+    if rest.startswith("("):
+        depth = 0
+        for end, ch in enumerate(rest):
+            depth += (ch == "(") - (ch == ")")
+            if depth == 0:
+                break
+        type_str, tail = rest[:end + 1], rest[end + 1:]
+    else:
+        t = _ARRAY_TYPE_RE.match(rest)
+        if not t:
+            return "", ""
+        type_str, tail = t.group(0), rest[t.end():]
+    op = re.match(r"\s+([\w\-]+)", tail)
+    return type_str, (op.group(1) if op else "")
+
+
 def _instr_opcode(line: str) -> str:
     # "%name = bf16[8,128]{1,0} all-reduce(...)" -> opcode after type
-    m = re.match(r"%?[\w\.\-]+\s*=\s*((?:\([^)]*\))|(?:[a-z0-9]+\[[0-9,]*\]"
-                 r"(?:{[^}]*})?))\s+([\w\-]+)", line)
-    return m.group(2) if m else ""
+    return _result_and_opcode(line)[1]
 
 
 def _instr_result_bytes(line: str) -> int:
-    eq = line.find("=")
-    rest = line[eq + 1:]
-    # result type is everything up to the opcode token
-    m = re.match(r"\s*(\([^)]*\)|[a-z0-9]+\[[0-9,]*\](?:{[^}]*})?)", rest)
-    return _shape_bytes(m.group(1)) if m else 0
+    return _shape_bytes(_result_and_opcode(line)[0])
+
+
+def _instr_operand_count(line: str, op: str) -> int:
+    """Top-level operands of ``op(...)`` in an instruction line."""
+    start = line.find(f" {op}(")
+    if start < 0:
+        return 1
+    depth, n, seen = 0, 1, False
+    for ch in line[start + len(op) + 2:]:
+        if ch in "([{":
+            depth += 1
+        elif ch in ")]}":
+            if depth == 0:
+                break
+            depth -= 1
+        elif ch == "," and depth == 0:
+            n += 1
+        elif not ch.isspace():
+            seen = True
+    return n if seen else 0
 
 
 def count_collectives(hlo: str) -> Dict[str, int]:
-    """Number of collective LAUNCHES per op kind in the HLO text (flat,
-    no while-trip multipliers — for auditing explicitly-scheduled
-    exchange programs, which have no loops).
+    """Number of collectives per op kind in the HLO text as the program
+    emitted them (flat, no while-trip multipliers — for auditing
+    explicitly-scheduled exchange programs, which have no loops).
 
-    Async pairs (``-start``/``-done``) count once.
+    Async pairs (``-start``/``-done``) count once.  XLA's collective
+    combiners merge independent collectives into one variadic op
+    (``all-reduce(%a, %b, %c)`` with a tuple result); each operand of
+    such an op counts as one collective, so the count is the same
+    whether or not the compiler combined them.
     """
     counts: Dict[str, int] = {}
     for name, lines in parse_computations(hlo).items():
@@ -93,7 +139,8 @@ def count_collectives(hlo: str) -> Dict[str, int]:
             base = op.replace("-start", "")
             if base in ("all-gather", "all-reduce", "reduce-scatter",
                         "all-to-all", "collective-permute"):
-                counts[base] = counts.get(base, 0) + 1
+                counts[base] = (counts.get(base, 0)
+                                + _instr_operand_count(line, op))
     return counts
 
 

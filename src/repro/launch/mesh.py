@@ -6,16 +6,20 @@ touches jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """``jax.make_mesh`` with Auto axes: the dry-run steers GSPMD with
+    ``with_sharding_constraint``, which only accepts Auto axes."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def data_axes(mesh) -> tuple:

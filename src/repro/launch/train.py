@@ -28,13 +28,14 @@ import sys
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax import shard_map
 
 from repro.configs import get_config
 from repro.core import (DistributedOptimizer, ExchangeConfig,
                         available_backends, available_codecs)
 from repro.data import make_pipeline
+from repro.launch.cache import enable_compile_cache
 from repro.models import build_model
 from repro.optim import adamw, noam_schedule
 from repro.training import Trainer, TrainerConfig, make_train_step
@@ -143,6 +144,15 @@ def print_exchange_schedule(args, model, params, opt, pipe,
     return g
 
 
+def place_on_mesh(tree, mesh, spec):
+    """``device_put`` a train-state tree where the ``shard_map``'d step
+    returns it (``spec``: one PartitionSpec or a tree of them), so the
+    first step compiles the program every later step reuses."""
+    return jax.device_put(tree, jax.tree_util.tree_map(
+        lambda s: NamedSharding(mesh, s), spec,
+        is_leaf=lambda s: isinstance(s, P)))
+
+
 def capture_training_trace(args, opt, model, params, pipe, g, step_fn,
                            result, ex_state, opt_state, axes, n_dev,
                            sparse_embedding) -> None:
@@ -186,7 +196,13 @@ def capture_training_trace(args, opt, model, params, pipe, g, step_fn,
     print(report_lib.render_table(rows))
 
 
-def main(argv=None) -> int:
+def train(argv=None) -> dict:
+    """Parse the CLI, build model, optimizer and mesh, and train.
+
+    Returns the ``Trainer.run`` result (final ``params``, ``opt_state``,
+    ``exchange_state`` and the logged ``history``) plus the resolved
+    ``config`` and worker count ``n_workers``; ``main`` is the CLI shell
+    around it."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="transformer-big")
     ap.add_argument("--reduced", action="store_true",
@@ -283,6 +299,7 @@ def main(argv=None) -> int:
                          "timeline view of the BucketSchedule; summarize "
                          "with scripts/trace_report.py")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.tune_cache is None:
         from repro.tuning.search import DEFAULT_CACHE_DIR
         args.tune_cache = DEFAULT_CACHE_DIR
@@ -317,12 +334,14 @@ def main(argv=None) -> int:
             shape = (n_dev,)
         mesh = Mesh(np.array(jax.devices()).reshape(shape), axes)
         pspec_batch = P(axes)
+        batch_sharding = NamedSharding(mesh, pspec_batch)
         batch_per_host = args.batch_per_worker * n_dev
         print(f"horovod mode: {n_dev} workers ({'x'.join(map(str, shape))}"
               f" {'/'.join(axes)}), global batch "
               f"{batch_per_host}x{args.seq_len} tokens")
     else:
         batch_per_host = args.batch_per_worker
+        batch_sharding = None
 
     pipe = make_pipeline(cfg, batch_per_host=batch_per_host,
                          seq_len=args.seq_len, seed=args.seed,
@@ -365,12 +384,16 @@ def main(argv=None) -> int:
                              in_specs=(P(), ostate_spec, P(axes),
                                        pspec_batch),
                              out_specs=(P(), ostate_spec, P(axes), P()),
-                             check_rep=False)
+                             check_vma=False)
         else:
             step = shard_map(step, mesh=mesh,
                              in_specs=(P(), ostate_spec, pspec_batch),
                              out_specs=(P(), ostate_spec, P()),
-                             check_rep=False)
+                             check_vma=False)
+        params = place_on_mesh(params, mesh, P())
+        opt_state = place_on_mesh(opt_state, mesh, ostate_spec)
+        if stateful:
+            ex_state = place_on_mesh(ex_state, mesh, P(axes))
     recorder = None
     if args.metrics_jsonl:
         from repro.telemetry.metrics import MetricsLogger, StepRecorder
@@ -381,7 +404,7 @@ def main(argv=None) -> int:
         total_steps=args.steps, log_every=args.log_every,
         checkpoint_every=args.checkpoint_every,
         checkpoint_dir=args.checkpoint_dir, resume=args.resume),
-        recorder=recorder)
+        recorder=recorder, batch_sharding=batch_sharding)
     result = trainer.run(params, opt_state, exchange_state=ex_state)
     if recorder is not None:
         # persist the Trainer's windowed history (previously dropped
@@ -394,6 +417,11 @@ def main(argv=None) -> int:
         capture_training_trace(args, opt, model, params, pipe, g, step,
                                result, ex_state, opt_state, axes, n_dev,
                                sparse_embedding)
+    return dict(result, config=cfg, n_workers=workers)
+
+
+def main(argv=None) -> int:
+    result = train(argv)
     final = result["history"][-1] if result["history"] else {}
     print(f"done: {final}")
     return 0

@@ -365,8 +365,11 @@ def mla_attention(p: Params, cfg: ArchConfig, x: jax.Array,
         out = decode_attention(qf, k, vv, length=new_cache["length"],
                                window=window, ring=new_cache["ring"])
     else:
+        # the Pallas kernel needs equal q/v head dims; MLA's differ
+        impl = ("xla_chunked" if attn_impl == "pallas" and qd != m.v_dim
+                else attn_impl)
         out = kops.flash_attention(qf, k, vv, causal=True,
-                                   window=window, impl=attn_impl)
+                                   window=window, impl=impl)
     out = out.reshape(b, s, h * m.v_dim)
     return jnp.einsum("bsf,fd->bsd", out, p["wo"]), new_cache
 

@@ -27,6 +27,9 @@ class Trainer:
     pipeline: Any                       # iterable of host batches
     config: TrainerConfig
     recorder: Any = None                # telemetry.metrics.StepRecorder
+    # where each global batch is placed (data-parallel runs shard it
+    # over the workers); None leaves it on the default device
+    batch_sharding: Any = None
 
     def run(self, params, opt_state, log: Callable[[str], None] = print,
             exchange_state: Any = None) -> Dict[str, Any]:
@@ -71,7 +74,7 @@ class Trainer:
             if rec is not None:
                 rec.step_start()
             t_fetch = time.perf_counter()
-            batch = {k: jax.numpy.asarray(v)
+            batch = {k: jax.device_put(v, self.batch_sharding)
                      for k, v in self.pipeline.batch_at(step).items()}
             data_ms = (time.perf_counter() - t_fetch) * 1e3
             window_data_ms += data_ms

@@ -123,7 +123,7 @@ def measure_candidates(candidates: Sequence[space_lib.Candidate],
     import jax
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from repro.core import DistributedOptimizer
     from repro.optim import adamw
@@ -183,7 +183,7 @@ def measure_candidates(candidates: Sequence[space_lib.Candidate],
 
             jitted = jax.jit(shard_map(fn, mesh=mesh, in_specs=in_specs,
                                        out_specs=out_specs,
-                                       check_rep=False))
+                                       check_vma=False))
             jax.block_until_ready(jitted(*args))    # compile
             jax.block_until_ready(jitted(*args))    # warm
             fns[idx] = (jitted, args)

@@ -6,16 +6,23 @@ installed (see requirements-dev.txt).  The fallback draws a fixed,
 per-test pseudo-random sample set — no shrinking, no database — which is
 enough to keep the property tests meaningful in minimal containers
 instead of failing at collection with ModuleNotFoundError.
+
+It also pins JAX to the CPU unless the caller chose a platform: the
+suite runs Pallas kernels in interpret mode on emulated devices, and a
+CPU-pinned process keeps no persistent compile cache in the checkout.
 """
 from __future__ import annotations
 
 import functools
 import inspect
+import os
 import sys
 import types
 import zlib
 
 import numpy as np
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 try:  # real hypothesis wins whenever it is available
     import hypothesis  # noqa: F401

@@ -31,7 +31,7 @@ def test_sparse_gather_equals_dense_reduce_across_workers():
     out = run_with_devices(textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.configs import get_config
         from repro.models import build_model
         from repro.core import DistributedOptimizer
@@ -54,7 +54,7 @@ def test_sparse_gather_equals_dense_reduce_across_workers():
             step = make_train_step(m, opt, sparse_embedding=True)
             sm = shard_map(step, mesh=mesh,
                            in_specs=(P(), P(), P('data')),
-                           out_specs=(P(), P(), P()), check_rep=False)
+                           out_specs=(P(), P(), P()), check_vma=False)
             p, s, met = jax.jit(sm)(params, opt.init(params), batch)
             results[name] = p
         diffs = [float(jnp.max(jnp.abs(a.astype(jnp.float32)
@@ -72,7 +72,7 @@ def test_allgather_slices_concatenates_across_workers():
     out = run_with_devices(textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.core import comm, IndexedSlices
 
         mesh = Mesh(np.array(jax.devices()), ('data',))
@@ -86,7 +86,7 @@ def test_allgather_slices_concatenates_across_workers():
         gi, gv = jax.jit(shard_map(f, mesh=mesh,
                                    in_specs=(P('data'), P('data')),
                                    out_specs=P('data'),
-                                   check_rep=False))(idx, vals)
+                                   check_vma=False))(idx, vals)
         print('ROWS', gi.shape, gv.shape)
         # every worker holds all 8*3 rows
         assert gi.shape == (8, 24) and gv.shape == (8, 24, 2)
@@ -100,7 +100,7 @@ def test_psum_matches_local_sum():
     out = run_with_devices(textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.core import comm
 
         mesh = Mesh(np.array(jax.devices()), ('data',))
@@ -108,7 +108,7 @@ def test_psum_matches_local_sum():
         def f(xx):
             return comm.all_reduce_dense(xx[0], 'data', average=False)[None]
         out = jax.jit(shard_map(f, mesh=mesh, in_specs=(P('data'),),
-                                out_specs=P('data'), check_rep=False))(x)
+                                out_specs=P('data'), check_vma=False))(x)
         np.testing.assert_allclose(np.asarray(out[0]),
                                    np.asarray(x.sum(0)), rtol=1e-6)
         print('OK')
@@ -120,7 +120,7 @@ def test_fused_allreduce_multi_device():
     out = run_with_devices(textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.core import fusion
 
         mesh = Mesh(np.array(jax.devices()), ('data',))
@@ -132,7 +132,7 @@ def test_fused_allreduce_multi_device():
                                           average=True)
             return {k: v[None] for k, v in out.items()}
         out = jax.jit(shard_map(f, mesh=mesh, in_specs=(P('data'),),
-                                out_specs=P('data'), check_rep=False))(tree)
+                                out_specs=P('data'), check_vma=False))(tree)
         np.testing.assert_allclose(np.asarray(out['a'][0]),
                                    np.ones((3, 3)), rtol=1e-6)
         print('OK')

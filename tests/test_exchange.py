@@ -503,7 +503,7 @@ def test_plan_equals_eager_exchange_across_workers():
         import functools
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.core import (DistributedOptimizer, IndexedSlices,
                                 accumulation, comm)
         from repro.optim import adamw
@@ -536,7 +536,7 @@ def test_plan_equals_eager_exchange_across_workers():
         def run(fn):
             sm = jax.jit(shard_map(fn, mesh=mesh,
                                    in_specs=(P('data'),) * 3,
-                                   out_specs=P('data'), check_rep=False))
+                                   out_specs=P('data'), check_vma=False))
             return np.asarray(sm(idx, vals, dense)[0])
 
         for sad, eager in [(True, eager_reduce), (False, eager_gather)]:
@@ -558,7 +558,7 @@ def test_reduce_scatter_bf16_matches_fused_allreduce():
         import functools
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.core import DistributedOptimizer, IndexedSlices
         from repro.optim import adamw
 
@@ -580,7 +580,7 @@ def test_reduce_scatter_bf16_matches_fused_allreduce():
         def run(opt):
             sm = jax.jit(shard_map(functools.partial(f, opt=opt),
                                    mesh=mesh, in_specs=(P('data'),) * 4,
-                                   out_specs=P('data'), check_rep=False))
+                                   out_specs=P('data'), check_vma=False))
             e, ww = sm(idx, vals, dense, w)
             return np.asarray(e[0]), np.asarray(ww[0])
 
@@ -606,7 +606,7 @@ def test_hierarchical_two_level_psum_matches_flat():
         import functools
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.core import DistributedOptimizer
         from repro.optim import adamw
 
@@ -627,7 +627,7 @@ def test_hierarchical_two_level_psum_matches_flat():
                                    mesh=mesh,
                                    in_specs=(P('pod', 'data'),),
                                    out_specs=P('pod', 'data'),
-                                   check_rep=False))
+                                   check_vma=False))
             outs[name] = np.asarray(sm(x)[0, 0])
         err = np.abs(outs['flat'] - outs['two_level']).max()
         assert err < 1e-6, err
@@ -645,7 +645,7 @@ def test_plan_collective_count_matches_lowered_hlo():
     out = run_with_devices(textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.core import DistributedOptimizer, IndexedSlices
         from repro.launch import hlo as hlo_lib
         from repro.optim import adamw
@@ -667,7 +667,7 @@ def test_plan_collective_count_matches_lowered_hlo():
                                        **kw)
             plan = opt.plan(tree)
             sm = shard_map(opt.exchange, mesh=mesh, in_specs=(P(),),
-                           out_specs=P(), check_rep=False)
+                           out_specs=P(), check_vma=False)
             hlo = jax.jit(sm).lower(tree).compile().as_text()
             counts = hlo_lib.count_collectives(hlo)
             # one gather bucket lowers to TWO all-gathers (idx + values)
@@ -688,7 +688,7 @@ def test_plan_equals_eager_for_every_codec_backend_pair():
         import functools
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.core import (DistributedOptimizer, ExchangeConfig,
                                 IndexedSlices, available_backends,
                                 available_codecs)
@@ -712,7 +712,7 @@ def test_plan_equals_eager_for_every_codec_backend_pair():
         def run(opt, mesh, spec):
             sm = jax.jit(shard_map(functools.partial(f, opt=opt),
                                    mesh=mesh, in_specs=(spec,) * 4,
-                                   out_specs=spec, check_rep=False))
+                                   out_specs=spec, check_vma=False))
             hlo = sm.lower(idx, vals, dense, w).compile().as_text()
             e, ww = sm(idx, vals, dense, w)
             return np.asarray(e)[0], np.asarray(ww)[0], hlo
@@ -768,7 +768,7 @@ def test_broadcast_params_backend_hot_swap_across_workers():
     out = run_with_devices(textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.serving import broadcast_params, broadcast_plan
 
         rng = np.random.default_rng(0)
@@ -793,7 +793,7 @@ def test_broadcast_params_backend_hot_swap_across_workers():
                 return jax.tree_util.tree_map(lambda x: x[None], out)
             sm = jax.jit(shard_map(f, mesh=mesh,
                                    in_specs=(P('data'), P(), P()),
-                                   out_specs=P('data'), check_rep=False))
+                                   out_specs=P('data'), check_vma=False))
             got = sm(flags, params, stale)
             tol = {'identity': 0.0, 'bf16': 2e-2, 'int8': 2e-2}[codec]
             for k in params:
@@ -973,7 +973,7 @@ def test_overlap_equals_fused_across_workers_bitwise():
         import functools
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.core import (DistributedOptimizer, ExchangeConfig,
                                 IndexedSlices)
         from repro.launch import hlo as hlo_lib
@@ -999,7 +999,7 @@ def test_overlap_equals_fused_across_workers_bitwise():
         def run(opt):
             sm = jax.jit(shard_map(functools.partial(f, opt=opt),
                                    mesh=mesh, in_specs=(P('data'),) * 4,
-                                   out_specs=P('data'), check_rep=False))
+                                   out_specs=P('data'), check_vma=False))
             hlo = sm.lower(idx, vals, dense, ws).compile().as_text()
             e, w = sm(idx, vals, dense, ws)
             return np.asarray(e)[0], np.asarray(w)[0], hlo

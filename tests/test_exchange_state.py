@@ -429,7 +429,7 @@ def test_stateful_api_bitwise_and_per_hop_audit_across_workers():
         import functools
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.core import DistributedOptimizer, ExchangeConfig
         from repro.optim import adamw
 
@@ -462,12 +462,12 @@ def test_stateful_api_bitwise_and_per_hop_audit_across_workers():
 
                 legacy = jax.jit(shard_map(
                     f_legacy, mesh=mesh, in_specs=(P('data'),),
-                    out_specs=P('data'), check_rep=False))(ws)
+                    out_specs=P('data'), check_vma=False))(ws)
                 stateful, _ = jax.jit(shard_map(
                     f_state, mesh=mesh,
                     in_specs=(P('data'), P('data')),
                     out_specs=(P('data'), P('data')),
-                    check_rep=False))(ws, st0)
+                    check_vma=False))(ws, st0)
                 assert np.array_equal(np.asarray(legacy)[0],
                                       np.asarray(stateful)[0]), \
                     (codec, overlap)
@@ -504,7 +504,7 @@ def test_error_feedback_improves_loss_across_workers():
         import functools
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.core import DistributedOptimizer, ExchangeConfig
         from repro.optim import adamw
 
@@ -541,7 +541,7 @@ def test_error_feedback_improves_loss_across_workers():
             sm = jax.jit(shard_map(step, mesh=mesh,
                 in_specs=(P(), P(), P('data'), P('data')),
                 out_specs=(P(), P(), P('data'), P()),
-                check_rep=False))
+                check_vma=False))
             opt_state = opt.init(params)
             for i in range(60):
                 params, opt_state, st, loss = sm(params, opt_state, st,

@@ -118,15 +118,28 @@ def test_flash_bf16():
 
 
 def test_flash_mla_mixed_head_dims_falls_back():
-    """MLA: v head dim != qk head dim must still be correct."""
+    """MLA: v head dim != qk head dim is refused by the Pallas kernel and
+    correct on the ``xla_chunked`` path its callers fall back to."""
     key = jax.random.PRNGKey(9)
     ks = jax.random.split(key, 3)
     q = jax.random.normal(ks[0], (1, 16, 2, 48), jnp.float32)
     k = jax.random.normal(ks[1], (1, 16, 2, 48), jnp.float32)
     v = jax.random.normal(ks[2], (1, 16, 2, 32), jnp.float32)
     exp = ref.attention_ref(q, k, v, causal=True)
-    out = ops.flash_attention(q, k, v, impl="pallas")
+    with pytest.raises(ValueError, match="equal q/v head dims"):
+        ops.flash_attention(q, k, v, impl="pallas")
+    out = ops.flash_attention(q, k, v, impl="xla_chunked")
     np.testing.assert_allclose(out, exp, rtol=3e-5, atol=3e-5)
+
+
+def test_pallas_interpret_follows_backend(monkeypatch):
+    """Interpreted on the CPU, native on the TPU, refused elsewhere."""
+    for backend, want in (("cpu", True), ("tpu", False)):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        assert ops.pallas_interpret() is want
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(NotImplementedError, match="gpu"):
+        ops.pallas_interpret()
 
 
 def test_window_equals_full_when_window_large():

@@ -111,7 +111,7 @@ def test_hlo_collective_bytes_psum():
     code = textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.launch import hlo as hlo_lib
         mesh = Mesh(np.array(jax.devices()), ('d',))
         def f(x):
@@ -158,3 +158,28 @@ ENTRY %main (a: f32[128]) -> f32[128] {
 """
     stats = hlo_lib.analyze_collectives(hlo)
     assert stats.get("all-reduce", 0) == 16 * 128 * 4
+
+
+# one combined all-reduce in the layout syntax of a compiled v5e program,
+# then two plain all-gathers and an async pair
+_COMBINED_TPU_HLO = """
+ENTRY %main (p0: bf16[1024], p1: bf16[256], p2: bf16[256]) -> bf16[1024] {
+  %all-reduce.2 = (bf16[1024]{0:T(1024)(128)(2,1)S(1)}, bf16[256]{0:T(256)}, bf16[256]{0:T(256)}) all-reduce(%p0, %p1, %p2), channel_id=1, replica_groups={{0,1,2,3}}, to_apply=%add
+  %all-gather.1 = s32[16]{0:T(256)} all-gather(%i0), dimensions={0}
+  %all-gather.2 = f32[16,8]{1,0:T(8,128)} all-gather(%v0), dimensions={0}
+  %ars = f32[8]{0} all-reduce-start(%x), to_apply=%add
+  %ard = f32[8]{0} all-reduce-done(%ars)
+  ROOT %gte = bf16[1024]{0} get-tuple-element(%all-reduce.2), index=0
+}
+"""
+
+
+def test_count_collectives_counts_combined_operands():
+    """A combined collective counts once per operand, so the count is
+    what the program emitted whether or not XLA merged it; TPU tuple
+    layouts with their own parentheses parse."""
+    counts = hlo_lib.count_collectives(_COMBINED_TPU_HLO)
+    assert counts == {"all-reduce": 4, "all-gather": 2}
+    coll = hlo_lib.analyze_collectives(_COMBINED_TPU_HLO)
+    assert coll["all-reduce"] == (1024 + 256 + 256) * 2 + 8 * 4
+    assert coll["all-gather"] == 16 * 4 + 16 * 8 * 4

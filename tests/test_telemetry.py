@@ -127,7 +127,7 @@ import jax, numpy as np
 jax.config.update("jax_platform_name", "cpu")
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.core import exchange
 from repro.launch import hlo as hlo_lib
 
@@ -137,7 +137,7 @@ plan = exchange.compile_plan(
     g, exchange.ExchangeConfig(sparse_as_dense=True, codec="int8"))
 mesh = Mesh(np.array(jax.devices()), ("data",))
 sm = shard_map(lambda gg: plan.execute(gg, "data"), mesh=mesh,
-               in_specs=(P(),), out_specs=P(), check_rep=False)
+               in_specs=(P(),), out_specs=P(), check_vma=False)
 txt = jax.jit(sm).lower(g).compile().as_text()
 counts = hlo_lib.count_collectives(txt)
 print("OPS", sum(counts.values()), plan.hlo_collectives(8))
@@ -174,7 +174,7 @@ import jax, numpy as np
 jax.config.update("jax_platform_name", "cpu")
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.core import exchange
 from repro.telemetry import trace as trace_lib
 
@@ -183,7 +183,7 @@ g = {"a": jnp.arange(1024, dtype=jnp.float32).reshape(32, 32),
 plan = exchange.compile_plan(g, CFG)
 mesh = Mesh(np.array(jax.devices()), ("data",))
 sm = shard_map(lambda gg: plan.execute(gg, "data"), mesh=mesh,
-               in_specs=(P(),), out_specs=P(), check_rep=False)
+               in_specs=(P(),), out_specs=P(), check_vma=False)
 rec = trace_lib.measure_wire(sm, g)
 got = rec.stage_wire_bytes()
 names = plan.stage_names()
@@ -203,7 +203,7 @@ import jax, numpy as np
 jax.config.update("jax_platform_name", "cpu")
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.core import exchange
 from repro.telemetry import trace as trace_lib
 
@@ -212,7 +212,7 @@ plan = exchange.compile_plan(g, exchange.ExchangeConfig(
     sparse_as_dense=True, backend="hierarchical", codec="int8"))
 mesh = Mesh(np.array(jax.devices()).reshape(2, 4), ("pod", "data"))
 sm = shard_map(lambda gg: plan.execute(gg, ("pod", "data")), mesh=mesh,
-               in_specs=(P(),), out_specs=P(), check_rep=False)
+               in_specs=(P(),), out_specs=P(), check_vma=False)
 rec = trace_lib.measure_wire(sm, g)
 got = rec.stage_wire_bytes()
 for n, s in zip(plan.stage_names(), plan.schedule.stages):
@@ -232,7 +232,7 @@ import jax, numpy as np
 jax.config.update("jax_platform_name", "cpu")
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.core import exchange
 from repro.optim import adamw, zero1 as z1
 from repro.telemetry import trace as trace_lib
@@ -249,7 +249,7 @@ sm = shard_map(lambda gg, pp, zz: z1.zero1_step(plan, base, gg, pp, zz,
                                                 "data")[0],
                mesh=mesh,
                in_specs=(P(), P(), z1.state_specs(plan, zst, "data")),
-               out_specs=P(), check_rep=False)
+               out_specs=P(), check_vma=False)
 rec = trace_lib.measure_wire(sm, g, params, zst)
 got = rec.stage_wire_bytes()
 for n, s in zip(plan.stage_names(), plan.schedule.stages):
@@ -262,7 +262,7 @@ plan2 = exchange.compile_plan(g, exchange.ExchangeConfig(
 st0 = plan2.init_state(n_workers=8)
 sm2 = shard_map(lambda gg, ss: plan2.execute(gg, "data", state=ss),
                 mesh=mesh, in_specs=(P(), P("data")),
-                out_specs=(P(), P("data")), check_rep=False)
+                out_specs=(P(), P("data")), check_vma=False)
 rec2 = trace_lib.measure_wire(sm2, g, st0)
 got2 = rec2.stage_wire_bytes()
 for n, s in zip(plan2.stage_names(), plan2.schedule.stages):
@@ -289,7 +289,7 @@ import jax, numpy as np, json
 jax.config.update("jax_platform_name", "cpu")
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.core import exchange
 from repro.telemetry import trace as trace_lib
 
@@ -299,7 +299,7 @@ plan = exchange.compile_plan(g, exchange.ExchangeConfig(
     sparse_as_dense=True, codec="int8", overlap=True))
 mesh = Mesh(np.array(jax.devices()), ("data",))
 sm = shard_map(lambda gg: plan.execute(gg, "data"), mesh=mesh,
-               in_specs=(P(),), out_specs=P(), check_rep=False)
+               in_specs=(P(),), out_specs=P(), check_vma=False)
 base = jax.jit(sm)(g)
 trace = trace_lib.capture_exchange_trace(
     plan, sm, (g,), ("data",), 8, out_path=OUT)

@@ -245,7 +245,7 @@ def test_wait_free_across_workers_bitwise():
         import functools
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.configs import get_config
         from repro.core import DistributedOptimizer, ExchangeConfig
         from repro.data import make_pipeline
@@ -281,7 +281,7 @@ def test_wait_free_across_workers_bitwise():
                     return opt.plan(g).execute_fused(g, ("data",))
 
                 kw = dict(mesh=mesh, in_specs=(P(), P("data")),
-                          out_specs=P(), check_rep=False)
+                          out_specs=P(), check_vma=False)
                 wf_sm = jax.jit(shard_map(wf, **kw))
                 hlo = wf_sm.lower(params, batch).compile().as_text()
                 out_wf = wf_sm(params, batch)

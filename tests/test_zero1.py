@@ -282,7 +282,7 @@ import functools
 import numpy as np
 import jax, jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.core import DistributedOptimizer, ExchangeConfig
 from repro.optim import adamw, apply_updates
 from repro.optim import zero1 as z1
@@ -307,7 +307,7 @@ def make_zero1(cfg):
     if ex0 is None:
         @functools.partial(shard_map, mesh=mesh,
             in_specs=(P(), zspec, (P("data"), P("data"))),
-            out_specs=(P(), zspec), check_rep=False)
+            out_specs=(P(), zspec), check_vma=False)
         def step(p, z, g):
             gg = {"a": g[0][0], "b": g[1][0]}
             np_, nz, _ = opt.zero1_step(gg, p, z)
@@ -316,7 +316,7 @@ def make_zero1(cfg):
     exspec = jax.tree_util.tree_map(lambda _: P("data"), ex0)
     @functools.partial(shard_map, mesh=mesh,
         in_specs=(P(), zspec, exspec, (P("data"), P("data"))),
-        out_specs=(P(), zspec, exspec), check_rep=False)
+        out_specs=(P(), zspec, exspec), check_vma=False)
     def step(p, z, e, g):
         gg = {"a": g[0][0], "b": g[1][0]}
         return opt.zero1_step(gg, p, z, exchange_state=e)
@@ -331,7 +331,7 @@ def run_replicated(cfg, steps):
     if ex0 is None:
         @functools.partial(shard_map, mesh=mesh,
             in_specs=(P(), (P("data"), P("data"))), out_specs=P(),
-            check_rep=False)
+            check_vma=False)
         def ex_fn(p, g):
             return opt.exchange({"a": g[0][0], "b": g[1][0]})
         for _ in range(steps):
@@ -341,7 +341,7 @@ def run_replicated(cfg, steps):
     exspec = jax.tree_util.tree_map(lambda _: P("data"), ex0)
     @functools.partial(shard_map, mesh=mesh,
         in_specs=(P(), exspec, (P("data"), P("data"))),
-        out_specs=(P(), exspec), check_rep=False)
+        out_specs=(P(), exspec), check_vma=False)
     def ex_fn(p, e, g):
         return opt.exchange({"a": g[0][0], "b": g[1][0]}, state=e)
     ecur = ex0
