@@ -2,7 +2,7 @@
 """Render the experiment artifacts into one human-readable report.
 
     PYTHONPATH=src python scripts/report.py [--pod 1pod|2pod]
-        [--metrics metrics.jsonl] [--trace trace.json]
+        [--metrics metrics.jsonl] [--trace TRACE_DIR]
 
 Aggregates experiments/dryrun/*.json (roofline terms), the hillclimb
 JSONs, and the multi-pod coverage into a terminal report — the quick
@@ -10,8 +10,8 @@ answer to "where does each architecture sit and what binds it".
 
 ``--metrics`` / ``--trace`` additionally render a training run's
 telemetry artifacts (the JSONL written by ``train.py --metrics-jsonl``
-and the Chrome trace from ``--trace-dir``) next to the static numbers,
-closing the predicted-vs-measured loop in one report.
+and the profile directory from ``--trace-dir``) next to the static
+numbers, closing the predicted-vs-measured loop in one report.
 """
 import argparse
 import glob
@@ -50,8 +50,7 @@ def render_metrics(path):
 def render_trace(path):
     from repro.telemetry import report as report_lib
 
-    trace = report_lib.load_trace(path)
-    rows = report_lib.predicted_vs_measured(trace)
+    rows = report_lib.summarize_profile(path)["rows"]
     print(f"=== exchange trace ({path}) ===")
     print(report_lib.render_table(rows))
     print(f"wire exact vs plan: {report_lib.wire_exact(rows)}")
@@ -63,7 +62,7 @@ def main():
     ap.add_argument("--metrics", default=None,
                     help="metrics JSONL from train.py --metrics-jsonl")
     ap.add_argument("--trace", default=None,
-                    help="Chrome trace from train.py --trace-dir")
+                    help="profile directory from train.py --trace-dir")
     args = ap.parse_args()
 
     shown_telemetry = False

@@ -1,26 +1,30 @@
 #!/usr/bin/env python3
-"""Summarize an exchange Chrome trace: predicted vs measured, per stage.
+"""Summarize a training profile: device time per layer and per exchange
+stage, predicted vs measured.
 
-    PYTHONPATH=src python scripts/trace_report.py TRACE.json [--json]
+    PYTHONPATH=src python scripts/trace_report.py TRACE_DIR [--json]
 
-The trace files written by ``train.py --trace-dir`` and
-``dryrun --audit-exchange --trace`` are self-contained (stage names,
-the plan's wire accounting, the tuner's predicted per-stage cost, and
-the runtime-measured wire bytes all ride in ``otherData``), so this
-never recompiles a plan — it just renders the loop closure:
+``TRACE_DIR`` is what ``train.py --trace-dir`` writes: the
+``jax.profiler`` capture of the loop's last steps (``.xplane.pb`` and a
+Perfetto trace under ``plugins/profile/``), the compiled step's HLO
+text (``step.hlo.txt``, which names each device op's scope) and the
+plan's ``exchange.json`` (stage names, the plan's wire accounting, the
+tuner's predicted per-stage cost, the runtime-measured wire bytes).
+It renders:
 
-* per stage: predicted µs vs measured collective µs, split into
-  exposed vs hidden (overlapped-under-compute) time;
+* per stage: predicted µs vs the stage's measured device µs per step,
+  split into exposed (no other op runs) and hidden time;
 * per stage: planned wire bytes vs the bytes the runtime wire counters
-  actually billed, and their ratio (1.000 = the plan's accounting is
-  exact at runtime, the ``--audit-exchange`` contract);
+  billed, and their ratio (1.000 = the plan's accounting is exact, the
+  ``--audit-exchange`` contract);
+* per step: device ms by layer scope (``model/...``, ``optim/update``,
+  ``exchange``), the rest, and idle time; idle share by Trainer span;
 * a machine-readable ``--json`` form for CI (the telemetry smoke
   asserts one row per schedule stage and ``wire_exact``).
 
-Exit status: 0 when the trace parses and every stage has a row; 2 on a
-malformed/empty trace.  Wire inexactness does NOT fail the exit code —
-timing drift is the thing this report exists to surface, and lossy
-backends may legitimately measure differently; CI asserts on the JSON.
+Exit status: 0 when the directory parses and every stage has a row; 2
+on a missing or malformed capture.  Wire inexactness does NOT fail the
+exit code — CI asserts on the JSON.
 """
 import argparse
 import json
@@ -31,53 +35,49 @@ from repro.telemetry import report as report_lib
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("trace", help="trace JSON written by telemetry.trace")
+    ap.add_argument("trace_dir", help="directory written by "
+                                      "train.py --trace-dir")
     ap.add_argument("--json", action="store_true",
                     help="emit the summary as JSON instead of a table")
     args = ap.parse_args(argv)
 
-    trace = report_lib.load_trace(args.trace)
-    names = trace.get("otherData", {}).get("stage_names", [])
-    if not names:
-        print("malformed trace: no otherData.stage_names", file=sys.stderr)
+    try:
+        summary = report_lib.summarize_profile(args.trace_dir)
+    except FileNotFoundError as e:
+        print(f"malformed trace directory: {e}", file=sys.stderr)
         return 2
-    rows = report_lib.predicted_vs_measured(trace)
-    summary = report_lib.summarize_trace(trace)
-    if len(rows) != len(names):
-        print(f"malformed trace: {len(rows)} rows for {len(names)} stages",
+    names, rows = summary["stage_names"], summary["rows"]
+    if not names:
+        print("malformed trace directory: no stage names in exchange.json",
+              file=sys.stderr)
+        return 2
+    if not summary["n_workers_traced"]:
+        print("malformed trace directory: no device ops in the capture",
               file=sys.stderr)
         return 2
 
     if args.json:
-        print(json.dumps({
-            "n_stages": len(rows),
-            "stage_names": names,
-            "mode": summary["mode"],
-            "codec": summary["codec"],
-            "backend": summary["backend"],
-            "n_workers_traced": summary["n_workers_traced"],
-            "step_us": summary["step_us"],
-            "wire_exact": report_lib.wire_exact(rows),
-            "rows": rows,
-        }, indent=2))
+        print(json.dumps(summary, indent=2))
         return 0
 
-    meta = trace.get("otherData", {})
-    print(f"trace: {args.trace}")
+    print(f"trace: {args.trace_dir}")
     print(f"mode={summary['mode']} codec={summary['codec']} "
           f"backend={summary['backend']} "
           f"workers_traced={summary['n_workers_traced']} "
-          f"profile={meta.get('profile')}")
-    if summary["step_us"] is not None:
-        print(f"step: {summary['step_us'] / 1e3:.2f} ms")
+          f"steps_traced={summary['n_steps_traced']}")
+    print(f"step: {summary['step_us'] / 1e3:.2f} ms on the device")
+    print("per step, ms: " + "  ".join(
+        f"{k}={v:.3f}" for k, v in summary["layers_ms"].items()))
+    print("device idle, % of the window, by host span: " + "  ".join(
+        f"{k}={v:.3f}" for k, v in summary["idle_share_by_span"].items()))
     print()
     print(report_lib.render_table(rows))
     exposed = sum(r["exposed_us"] for r in rows)
     hidden = sum(r["hidden_us"] for r in rows)
     total = exposed + hidden
     if total:
-        print(f"\ncomm: {total / 1e3:.2f} ms total, "
-              f"{hidden / total * 100:.0f}% hidden under compute")
+        print(f"\nexchange: {total / 1e3:.3f} ms per step, "
+              f"{hidden / total * 100:.0f}% hidden under other ops")
     print(f"wire exact vs plan: {report_lib.wire_exact(rows)}")
     return 0
 

@@ -921,7 +921,6 @@ class ExchangePlan:
         with jax.named_scope("quantize"):
             wire, scale = codec.encode(s.values,
                                        use_kernel=self.config.use_kernel)
-        wire = _telemetry.tap("pack", wire)
         rows = s.values.shape[0]
         if not axes:
             return (s.indices, wire, scale, rows)
@@ -993,7 +992,7 @@ class ExchangePlan:
         bucket = self.dense_buckets[stage.bucket_id]
         codec = self.config.codec_obj
         be = self.config.backend_obj
-        buf = _telemetry.tap("pack", self.pack_bucket(bucket, leaves))
+        buf = self.pack_bucket(bucket, leaves)
         if codec.linear and not codec.stateful:
             if not axes:
                 return (buf,), bstate
@@ -1061,10 +1060,6 @@ class ExchangePlan:
                                                       axes, p, bstate)
             else:
                 inflight = self._launch_gather(stage, leaves, axes)
-            if _telemetry.tracer() is not None and inflight \
-                    and isinstance(inflight[0], jax.Array):
-                inflight = (_telemetry.tap("collective", inflight[0]),
-                            ) + tuple(inflight[1:])
             return inflight, bstate
 
     def finish_stage(self, stage: BucketStage, inflight: Tuple,
@@ -1080,9 +1075,6 @@ class ExchangePlan:
             else:
                 self._finish_gather(stage, inflight, out, inv_scale,
                                     axes, p)
-            if _telemetry.tracer() is not None:
-                i0 = min(stage.leaf_ids)
-                out[i0] = _telemetry.tap("unpack", out[i0])
 
     def _flatten_checked(self, grads) -> List[Any]:
         leaves, treedef = jax.tree_util.tree_flatten(grads,
@@ -1111,11 +1103,6 @@ class ExchangePlan:
             for i in stage.leaf_ids:
                 acc[i] = _accumulate_leaf(raw[i], self.leaf_specs[i],
                                           self.config)
-            if _telemetry.tracer() is not None:
-                for i in stage.leaf_ids:
-                    if isinstance(acc[i], jax.Array):
-                        acc[i] = _telemetry.tap("accumulate", acc[i])
-                        break
 
     # -- codec state ---------------------------------------------------------
     def init_state(self, n_workers: int = 1) -> ExchangeState:
@@ -1349,9 +1336,7 @@ class ExchangePlan:
         path bit for bit.  Returns ``(shard, new codec state)``."""
         name = self.stage_name(stage)
         with jax.named_scope(name), _telemetry.stage_scope(name):
-            shard, bstate = self._zero1_grad_shard(stage, leaves, axes,
-                                                   p, bstate)
-            return _telemetry.tap("collective", shard), bstate
+            return self._zero1_grad_shard(stage, leaves, axes, p, bstate)
 
     def _zero1_grad_shard(self, stage: BucketStage, leaves: List[Any],
                           axes: Tuple[str, ...], p: int, bstate
@@ -1360,7 +1345,7 @@ class ExchangePlan:
         codec = self.config.codec_obj
         be = self.config.backend_obj
         shard_elems = self.zero1_shard_elems(stage, p)
-        buf = _telemetry.tap("pack", self.pack_bucket(bucket, leaves))
+        buf = self.pack_bucket(bucket, leaves)
         if codec.linear:
             if codec.stateful:
                 # e.g. bf16+ef: the compensated wire still sums in flight
@@ -1424,12 +1409,7 @@ class ExchangePlan:
                 per = g_wire.astype(jnp.float32).reshape(p, shard_elems)
                 per = per * g_scale.astype(jnp.float32).reshape(p, 1)
                 buf = per.reshape(-1)
-            if _telemetry.tracer() is not None:
-                buf = _telemetry.tap("collective", buf)
             self.unpack_bucket(bucket, buf[:bucket.n_elems], out, None)
-            if _telemetry.tracer() is not None:
-                i0 = min(stage.leaf_ids)
-                out[i0] = _telemetry.tap("unpack", out[i0])
 
 
 # ---------------------------------------------------------------------------

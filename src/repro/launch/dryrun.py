@@ -412,23 +412,19 @@ def audit_exchange_plan(arch: str = "transformer-big", n_workers: int = 8,
 
     trace_info: Dict[str, Any] = {}
     if trace_dir:
-        # runtime leg of the audit: actually run one instrumented step
-        # (wire counters + host-timestamp taps) and diff it against the
-        # same plan accounting the static HLO check below verifies
-        import os
-
+        # runtime wire leg of the audit: one abstract evaluation bills
+        # each collective call site's wire bytes to its stage, diffed
+        # against the same plan accounting the static HLO check verifies
         from repro.telemetry import report as report_lib
         from repro.telemetry import trace as trace_lib
 
-        os.makedirs(trace_dir, exist_ok=True)
-        out_path = os.path.join(trace_dir, "trace.json")
-        trace = trace_lib.capture_exchange_trace(
-            plan, ex, lower_args, axis_name, workers,
-            profile=profile, out_path=out_path,
-            extra_meta={"arch": arch, "source": "dryrun"})
-        rows = report_lib.predicted_vs_measured(trace)
+        meta = trace_lib.plan_trace_meta(
+            plan, workers, profile=profile,
+            measured=trace_lib.measure_wire(ex, *lower_args))
+        meta.update(arch=arch, source="dryrun")
+        rows = report_lib.stage_rows(meta)
         trace_info = dict(
-            trace_path=out_path,
+            trace_path=trace_lib.write_meta(meta, trace_dir),
             runtime_wire_exact=report_lib.wire_exact(rows),
             trace_table=report_lib.render_table(rows))
 
@@ -784,9 +780,10 @@ def main(argv=None) -> int:
     ap.add_argument("--loss-chunk", type=int, default=512)
     ap.add_argument("--trace", default=None, metavar="DIR",
                     help="with --audit-exchange (shard_map mode): also "
-                         "RUN one instrumented exchange step, write a "
-                         "Chrome trace to DIR/trace.json, and report "
-                         "runtime-measured wire vs the plan accounting")
+                         "bill the wire bytes of one abstract evaluation "
+                         "of the exchange per stage, write them with the "
+                         "plan's accounting to DIR/exchange.json, and "
+                         "report runtime-measured wire vs the plan")
     ap.add_argument("--out", default=None)
     ap.add_argument("--print-hlo", action="store_true")
     args = ap.parse_args(argv)
@@ -831,7 +828,7 @@ def main(argv=None) -> int:
         table = result.pop("trace_table", None)
         print(json.dumps(result, indent=2, default=str))
         if table:
-            print("\npredicted vs measured (runtime trace):")
+            print("\nplanned vs measured wire (runtime counters):")
             print(table)
         if args.out:
             with open(args.out, "w") as f:

@@ -41,6 +41,9 @@ from repro.optim import adamw, noam_schedule
 from repro.training import Trainer, TrainerConfig, make_train_step
 
 
+TRACE_STEPS = 2     # steps at the end of a run that --trace-dir profiles
+
+
 def dist_axes(args, backend=None):
     """Mesh axis names for --dist horovod (the hierarchical backend
     spans two axes: within-pod + cross-pod).  ``backend`` overrides
@@ -153,16 +156,12 @@ def place_on_mesh(tree, mesh, spec):
         is_leaf=lambda s: isinstance(s, P)))
 
 
-def capture_training_trace(args, opt, model, params, pipe, g, step_fn,
-                           result, ex_state, opt_state, axes, n_dev,
-                           sparse_embedding) -> None:
-    """--trace-dir: capture ONE instrumented step at the final weights
-    and write the Chrome trace + predicted-vs-measured table.  The
-    training loop itself ran untraced — taps lower into a fresh jit of
-    the same step function, so capture costs one extra compile, not a
-    per-step tax."""
-    import os
-
+def write_exchange_meta(args, opt, model, params, pipe, g, step_fn,
+                        result, n_dev, sparse_embedding) -> None:
+    """--trace-dir: beside the profile that ``Trainer.run`` captured,
+    write the plan's ``exchange.json`` (stage names, wire accounting,
+    the tuner's prediction, and the wire bytes one abstract evaluation
+    of the step bills per stage) and print the report."""
     from repro.telemetry import report as report_lib
     from repro.telemetry import trace as trace_lib
 
@@ -171,29 +170,26 @@ def capture_training_trace(args, opt, model, params, pipe, g, step_fn,
                                   sparse_embedding)
     plan = opt.plan(g)
     batch = {k: jnp.asarray(v) for k, v in pipe.batch_at(0).items()}
-    final_params = result["params"]
-    final_opt = result["opt_state"]
+    fn_args = (result["params"], result["opt_state"])
     if result["exchange_state"] is not None:
-        fn_args = (final_params, final_opt, result["exchange_state"],
-                   batch)
-    else:
-        fn_args = (final_params, final_opt, batch)
+        fn_args += (result["exchange_state"],)
     if args.dist == "horovod":
         n_workers = ((2, n_dev // 2)
                      if opt.exchange_config.backend == "hierarchical"
                      else n_dev)
     else:
         n_workers = 1
-    os.makedirs(args.trace_dir, exist_ok=True)
-    out_path = os.path.join(args.trace_dir, "trace.json")
-    trace = trace_lib.capture_exchange_trace(
-        plan, step_fn, fn_args, axes or (), n_workers,
-        profile=args.profile, out_path=out_path,
-        extra_meta={"arch": args.arch, "dist": args.dist,
-                    "steps": args.steps})
-    print(f"trace written: {out_path}")
-    rows = report_lib.predicted_vs_measured(trace)
-    print(report_lib.render_table(rows))
+    wire = trace_lib.measure_wire(step_fn, *fn_args, batch)
+    meta = trace_lib.plan_trace_meta(plan, n_workers, profile=args.profile,
+                                     measured=wire)
+    meta.update(arch=args.arch, dist=args.dist, steps=args.steps,
+                trace_steps=TRACE_STEPS)
+    trace_lib.write_meta(meta, args.trace_dir)
+    print(f"trace written: {args.trace_dir}")
+    summary = report_lib.summarize_profile(args.trace_dir)
+    print("per step, ms: " + "  ".join(
+        f"{k}={v:.3f}" for k, v in summary["layers_ms"].items()))
+    print(report_lib.render_table(summary["rows"]))
 
 
 def train(argv=None) -> dict:
@@ -292,12 +288,12 @@ def train(argv=None) -> dict:
                          "skipped steps) and the run history to this "
                          "JSONL file (see docs/observability.md)")
     ap.add_argument("--trace-dir", default=None,
-                    help="after training, capture one instrumented step "
-                         "(host-timestamp taps at every exchange phase "
-                         "boundary + runtime wire-byte counters) and "
-                         "write a Chrome-trace JSON here — the Horovod-"
-                         "timeline view of the BucketSchedule; summarize "
-                         "with scripts/trace_report.py")
+                    help=f"profile the loop's last {TRACE_STEPS} steps "
+                         "with jax.profiler into this directory (a "
+                         "Perfetto trace, the compiled step's HLO text "
+                         "and the plan's exchange.json with runtime "
+                         "wire-byte counters); summarize with "
+                         "scripts/trace_report.py")
     args = ap.parse_args(argv)
     enable_compile_cache()
     if args.tune_cache is None:
@@ -403,7 +399,8 @@ def train(argv=None) -> dict:
     trainer = Trainer(model, step, pipe, TrainerConfig(
         total_steps=args.steps, log_every=args.log_every,
         checkpoint_every=args.checkpoint_every,
-        checkpoint_dir=args.checkpoint_dir, resume=args.resume),
+        checkpoint_dir=args.checkpoint_dir, resume=args.resume,
+        profile_dir=args.trace_dir, profile_steps=TRACE_STEPS),
         recorder=recorder, batch_sharding=batch_sharding)
     result = trainer.run(params, opt_state, exchange_state=ex_state)
     if recorder is not None:
@@ -414,9 +411,8 @@ def train(argv=None) -> dict:
         recorder.close()
         print(f"metrics written: {args.metrics_jsonl}")
     if args.trace_dir:
-        capture_training_trace(args, opt, model, params, pipe, g, step,
-                               result, ex_state, opt_state, axes, n_dev,
-                               sparse_embedding)
+        write_exchange_meta(args, opt, model, params, pipe, g, step,
+                            result, n_dev, sparse_embedding)
     return dict(result, config=cfg, n_workers=workers)
 
 

@@ -31,6 +31,7 @@ from repro.models import layers as L
 from repro.models import ssm as S
 from repro.models import xlstm as X
 from repro.models.activation_sharding import constrain_batch
+from repro.telemetry import hooks as scopes
 
 Params = Dict[str, Any]
 
@@ -65,15 +66,27 @@ def _block(p: Params, cfg: ArchConfig, x: jax.Array, positions,
            ) -> Tuple[jax.Array, Optional[Dict], jax.Array]:
     """Generic attention+FFN block (dense/moe/vlm/audio)."""
     attn_fn = L.mla_attention if cfg.mla is not None else L.attention
-    a, new_cache = attn_fn(p["attn"], cfg, L.rmsnorm(p["norm1"], x,
-                                                     cfg.norm_eps),
-                           positions, kv_cache=cache, window=window,
-                           attn_impl=attn_impl)
-    x = x + a
+    with jax.named_scope(scopes.SELF_ATTN):
+        a, new_cache = attn_fn(p["attn"], cfg,
+                               L.rmsnorm(p["norm1"], x, cfg.norm_eps),
+                               positions, kv_cache=cache, window=window,
+                               attn_impl=attn_impl)
+        x = x + a
     if enc is not None and "xattn" in p:
-        x = x + L.cross_attention(p["xattn"], cfg,
-                                  L.rmsnorm(p["norm_x"], x, cfg.norm_eps),
-                                  enc, attn_impl=attn_impl)
+        with jax.named_scope(scopes.CROSS_ATTN):
+            x = x + L.cross_attention(
+                p["xattn"], cfg, L.rmsnorm(p["norm_x"], x, cfg.norm_eps),
+                enc, attn_impl=attn_impl)
+    with jax.named_scope(scopes.FFN):
+        f, aux = _ffn(p, cfg, x, cache, moe_mode)
+        x = constrain_batch(x + f)
+    return x, new_cache, aux
+
+
+def _ffn(p: Params, cfg: ArchConfig, x: jax.Array, cache: Optional[Dict],
+         moe_mode: str) -> Tuple[jax.Array, jax.Array]:
+    """The block's feed-forward (MLP or experts) on ``norm2(x)``:
+    (output, router aux loss)."""
     h = L.rmsnorm(p["norm2"], x, cfg.norm_eps)
     aux = jnp.zeros((), jnp.float32)
     if cfg.moe is not None:
@@ -97,7 +110,7 @@ def _block(p: Params, cfg: ArchConfig, x: jax.Array, positions,
                                dropless=cache is not None)
     else:
         f = L.mlp(p["ffn"], h)
-    return constrain_batch(x + f), new_cache, aux
+    return f, aux
 
 
 # ---------------------------------------------------------------------------
@@ -170,7 +183,9 @@ class Model:
         cfg = self.cfg
         tokens = batch["tokens"]
         b, s = tokens.shape
-        x = constrain_batch(L.embed(params["embedding"], tokens, tap=taps))
+        with jax.named_scope(scopes.EMBED):
+            x = constrain_batch(L.embed(params["embedding"], tokens,
+                                        tap=taps))
         enc = None
         n_prefix = 0
         if cfg.frontend is not None:
@@ -181,30 +196,38 @@ class Model:
                 n_prefix = fe.shape[1]
                 x = jnp.concatenate([fe, x], axis=1)
         positions = jnp.arange(x.shape[1])
-
-        if cfg.family == "hybrid":
-            x = self._hybrid_forward(params, x, positions, window, attn_impl,
-                                     remat)
-            aux = jnp.zeros((), jnp.float32)
-        elif cfg.family == "ssm":
-            x = self._xlstm_forward(params, x, remat)
-            aux = jnp.zeros((), jnp.float32)
-        else:
-            def block_fn(lp, xx):
-                return _block(lp, cfg, xx, positions, None, enc,
-                              window, attn_impl)
-            if remat:
-                block_fn = jax.checkpoint(block_fn)
-
-            def body(carry, lp):
-                xx, aux = carry
-                xx, _, a = block_fn(lp, xx)
-                return (xx, aux + a), None
-            (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                                       params["layers"])
+        with jax.named_scope(scopes.LAYERS):
+            x, aux = self._stack(params, x, positions, enc, window,
+                                 attn_impl, remat)
         x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
         if n_prefix:
             x = x[:, n_prefix:]
+        return x, aux
+
+    def _stack(self, params, x, positions, enc, window, attn_impl, remat):
+        """The layer stack, scanned over its stacked params: (hidden
+        states, router aux loss)."""
+        cfg = self.cfg
+        if cfg.family == "hybrid":
+            x = self._hybrid_forward(params, x, positions, window, attn_impl,
+                                     remat)
+            return x, jnp.zeros((), jnp.float32)
+        if cfg.family == "ssm":
+            return (self._xlstm_forward(params, x, remat),
+                    jnp.zeros((), jnp.float32))
+
+        def block_fn(lp, xx):
+            return _block(lp, cfg, xx, positions, None, enc, window,
+                          attn_impl)
+        if remat:
+            block_fn = jax.checkpoint(block_fn)
+
+        def body(carry, lp):
+            xx, aux = carry
+            xx, _, a = block_fn(lp, xx)
+            return (xx, aux + a), None
+        (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
+                                   params["layers"])
         return x, aux
 
     def _hybrid_forward(self, params, x, positions, window, attn_impl,
@@ -305,9 +328,10 @@ class Model:
             tot, cnt = carry
             return (tot + jnp.sum(nll), cnt + jnp.sum(mm)), None
 
-        (tot, cnt), _ = jax.lax.scan(
-            chunk_loss, (jnp.zeros((), jnp.float32),
-                         jnp.zeros((), jnp.float32)), (hc, lc, mc))
+        with jax.named_scope(scopes.HEAD):
+            (tot, cnt), _ = jax.lax.scan(
+                chunk_loss, (jnp.zeros((), jnp.float32),
+                             jnp.zeros((), jnp.float32)), (hc, lc, mc))
         ce = tot / jnp.maximum(cnt, 1.0)
         total = ce
         if cfg.moe is not None:

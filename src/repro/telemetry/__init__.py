@@ -3,15 +3,17 @@
 Modules (lazily imported — ``hooks`` is the only one the hot path
 touches, and it is stdlib-only):
 
-* ``hooks``   — process-global hook points (wire recorder, tracer,
-  stage scopes).  Core modules import this directly.
-* ``trace``   — StepTracer (host-timestamp taps via ``io_callback``),
-  Chrome-trace/Perfetto export, ``measure_wire`` (abstract-eval wire
-  counting against the plan's accounting).
+* ``hooks``   — process-global hook points (wire recorder, stage
+  scopes) and the names of the step's device scopes and the
+  Trainer's host spans.  Core modules import this directly.
+* ``trace``   — ``measure_wire`` (abstract-eval wire counting against
+  the plan's accounting) and ``load_profile`` (the ``jax.profiler``
+  capture of the real loop, device ops resolved to their scopes).
 * ``metrics`` — counters / gauges / histograms, a JSONL sink, and the
   Trainer's ``StepRecorder``.
-* ``report``  — trace summarization: per-stage exposed-vs-hidden comm
-  and the predicted-vs-measured diff against ``tuning.cost``.
+* ``report``  — profile summarization: device time per layer and per
+  exchange stage (exposed vs hidden), idle time by Trainer span, and
+  the predicted-vs-measured diff against ``tuning.cost``.
 """
 from __future__ import annotations
 
@@ -22,13 +24,12 @@ _LAZY = {
     "metrics": "repro.telemetry.metrics",
     "report": "repro.telemetry.report",
     # convenience re-exports
-    "StepTracer": "repro.telemetry.trace",
     "measure_wire": "repro.telemetry.trace",
-    "chrome_trace": "repro.telemetry.trace",
+    "load_profile": "repro.telemetry.trace",
     "MetricsLogger": "repro.telemetry.metrics",
     "StepRecorder": "repro.telemetry.metrics",
     "LatencyHistogram": "repro.telemetry.metrics",
-    "summarize_trace": "repro.telemetry.report",
+    "summarize_profile": "repro.telemetry.report",
     "predicted_vs_measured": "repro.telemetry.report",
     "render_table": "repro.telemetry.report",
 }

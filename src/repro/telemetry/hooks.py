@@ -1,36 +1,74 @@
-"""Process-global telemetry hook points.
+"""Process-global telemetry hook points and the trace vocabulary.
 
 This module is the *leaf* of the telemetry package: it is stdlib-only
 (no jax, no repro imports) so that hot-path modules (``core.comm``,
-``core.backend``, ``core.exchange``) can import it unconditionally
-without creating import cycles or pulling tracing machinery into the
-default path.
+``core.backend``, ``core.exchange``, ``models``, ``training``) can
+import it unconditionally without creating import cycles or pulling
+tracing machinery into the default path.
 
-Design contract — zero overhead when disabled:
+Two things live here:
 
-* ``wire_recorder()`` / ``tracer()`` return ``None`` unless something
-  was explicitly installed.  Every call site gates on that *before*
-  doing any work, so the disabled path costs one global read and one
-  ``is None`` check at **trace time only** (all call sites run under
-  ``jax.jit`` tracing; nothing here executes per training step).
-* Recorders are installed around a single abstract evaluation
-  (``telemetry.trace.measure_wire``) or a single instrumented
-  compilation (``telemetry.trace.StepTracer.capture_step``) — never
-  left active across ordinary training.
+* The names every layer of the training step is known by in a trace.
+  ``jax.named_scope`` writes a device scope into each HLO op's
+  ``op_name`` metadata, so the profiler's device ops carry it; JAX
+  keeps it through autodiff, so backward ops read e.g.
+  ``transpose(jvp(model/ffn))/...`` and a scope counts forward plus
+  backward.  ``jax.profiler.TraceAnnotation`` spans land on the host's
+  Python line of the same trace (named for the interpreter:
+  ``python``, ``python3``), on the device ops' clock.  No name is a
+  substring of another.
+* The wire recorder: ``wire_recorder()`` returns ``None`` unless one
+  was installed around a single abstract evaluation
+  (``telemetry.trace.measure_wire``).  Every call site gates on that
+  before doing any work, so the disabled path costs one global read at
+  trace time only; nothing here executes per training step.
 """
 from __future__ import annotations
 
+import re
 import threading
 from contextlib import contextmanager
 
 __all__ = [
     "WireRecorder", "wire_recorder", "install_wire_recorder",
-    "clear_wire_recorder", "tracer", "install_tracer", "clear_tracer",
-    "stage_scope", "current_stage", "record_collective", "tap",
-    "UNATTRIBUTED",
+    "clear_wire_recorder", "stage_scope", "current_stage",
+    "record_collective", "UNATTRIBUTED", "EMBED", "LAYERS", "SELF_ATTN",
+    "CROSS_ATTN", "FFN", "HEAD", "OPTIM", "EXCHANGE", "LAYER_SCOPES",
+    "STEP", "FETCH", "DEVICE_PUT", "DISPATCH", "LOG", "CHECKPOINT",
+    "TRAINER_SPANS", "in_scope",
 ]
 
 UNATTRIBUTED = "unattributed"
+
+# -- device scopes (``jax.named_scope``) of the training step ---------------
+EMBED = "model/embed"            # Model.forward: lookup + sparse taps
+LAYERS = "model/layers"          # Model.forward: the scanned layer stack;
+#   it holds the next three, and on its own the scan's plumbing (each
+#   layer's weight slice, the residuals stored for the backward)
+SELF_ATTN = "model/self_attn"    # _block: norm1, attention, residual
+CROSS_ATTN = "model/cross_attn"  # _block: norm_x, cross-attention, residual
+FFN = "model/ffn"                # _block: norm2, MLP / experts, residual
+HEAD = "model/head"              # Model.loss: tied logits + cross-entropy
+OPTIM = "optim/update"           # train_step: optimizer update + apply
+EXCHANGE = "exchange"            # core/exchange.py: exchange/sNN/<kind>/...
+LAYER_SCOPES = (EMBED, LAYERS, SELF_ATTN, CROSS_ATTN, FFN, HEAD, OPTIM)
+
+# -- host spans (``jax.profiler``) of ``Trainer.run``, in step order --------
+STEP = "train"                   # StepTraceAnnotation around one step
+FETCH = "trainer/fetch"          # pipeline.batch_at
+DEVICE_PUT = "trainer/device_put"
+DISPATCH = "trainer/dispatch"    # the jitted step's call
+LOG = "trainer/log"              # log boundary: host reads, recorder flush
+CHECKPOINT = "trainer/checkpoint"
+TRAINER_SPANS = (FETCH, DEVICE_PUT, DISPATCH, LOG, CHECKPOINT)
+
+
+def in_scope(path: str, scope: str) -> bool:
+    """Whether an op's ``op_name`` path lies under ``scope``, forward
+    (``.../model/ffn/dot_general``) or differentiated
+    (``transpose(jvp(model/ffn))/...``)."""
+    return re.search(r"(^|[/(])" + re.escape(scope) + r"([/)]|$)",
+                     path) is not None
 
 # Telemetry state is intentionally process-global (not thread-local):
 # recorders are installed around a single trace/lowering, and jax may
@@ -38,7 +76,6 @@ UNATTRIBUTED = "unattributed"
 # clear; reads are plain (benign under CPython).
 _LOCK = threading.Lock()
 _WIRE = None
-_TRACER = None
 _STAGE: list[str] = []
 
 
@@ -100,28 +137,10 @@ def clear_wire_recorder() -> None:
         _WIRE = None
 
 
-def tracer():
-    """The installed StepTracer (telemetry.trace), or None."""
-    return _TRACER
-
-
-def install_tracer(t) -> None:
-    global _TRACER
-    with _LOCK:
-        if _TRACER is not None:
-            raise RuntimeError("a tracer is already installed")
-        _TRACER = t
-
-
-def clear_tracer() -> None:
-    global _TRACER
-    with _LOCK:
-        _TRACER = None
-
 
 @contextmanager
 def stage_scope(label: str):
-    """Attribute nested ``record_collective`` / ``tap`` calls to a stage.
+    """Attribute nested ``record_collective`` calls to a stage.
 
     No-op-cheap: maintains a plain list even when telemetry is off (a
     trace-time append/pop, nothing captured into the jaxpr).
@@ -148,16 +167,3 @@ def record_collective(kind: str, nbytes: float) -> None:
     if rec is not None:
         rec.record(kind, nbytes, current_stage())
 
-
-def tap(phase: str, value):
-    """Phase-boundary marker.
-
-    When a tracer is installed this threads ``value`` through a host
-    timestamp callback (see ``telemetry.trace.StepTracer.tap``) and
-    returns the result; otherwise it returns ``value`` unchanged — the
-    disabled path inserts NOTHING into the traced computation.
-    """
-    t = _TRACER
-    if t is None:
-        return value
-    return t.tap(phase, current_stage(), value)

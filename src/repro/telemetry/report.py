@@ -1,111 +1,199 @@
-"""Trace summarization: exposed-vs-hidden comm, predicted-vs-measured.
+"""Trace summarization: where a step's device time goes, per layer and
+per exchange stage, from the profiler's capture of the real loop.
 
-Operates on the self-contained Chrome-trace dicts written by
-``telemetry.trace`` — stage names, the plan's wire accounting, the
-tuner's per-stage prediction, and the runtime-measured wire bytes all
-ride in ``otherData``, so summarizing a trace needs neither the model
-nor a recompiled plan (the CLI is ``scripts/trace_report.py``).
+Operates on a ``telemetry.trace.Profile`` (``load_profile`` of the
+directory ``train.py --trace-dir`` writes): device ops per chip, each
+with its scope path, the Trainer's host spans, and the plan's
+``exchange.json`` (stage names, wire accounting, the tuner's per-stage
+prediction, runtime-measured wire bytes).  The CLI is
+``scripts/trace_report.py``.
 
-Definitions (per worker, then averaged):
+Definitions (per chip, per step, then averaged over chips):
 
-* a stage's **collective interval** is its ``collective`` slice;
-* **compute intervals** are every non-collective slice of the same
-  worker (any stage) — accumulate/pack/unpack work the scheduler can
-  overlap against;
-* **exposed** comm is the part of a collective interval covered by no
-  compute interval; **hidden** is the rest.  Hidden/total is the
-  overlap win the staged/wait-free schedules exist to maximise.
+* a layer's **device time** is the union of the intervals of the ops
+  whose innermost scope it is; JAX keeps a scope through autodiff, so
+  a model layer counts forward plus backward;
+* a stage's **exposed** time is the part of its ops' union that no op
+  outside the stage covers (an op that encloses others, such as a
+  ``while`` around its body, covers nothing itself); **hidden** is the
+  rest.  Hidden/total is
+  the overlap the staged/wait-free schedules exist to maximise;
+* **idle** time is the capture window (first op to last op) that no
+  device op covers, split by the Trainer span the host was inside;
+  **loop** time is what an enclosing op (a ``while`` around its body)
+  covers beyond its body's ops.
 """
 from __future__ import annotations
 
 import json
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
+
+from repro.telemetry import hooks
+from repro.telemetry.trace import Profile, load_profile
+
+Intervals = List[Tuple[int, int]]
+LAYERS = hooks.LAYER_SCOPES + (hooks.EXCHANGE,)
 
 
-def load_trace(path: str) -> Dict[str, Any]:
-    with open(path) as f:
-        return json.load(f)
-
-
-def _slices(trace: Dict[str, Any]) -> List[Dict[str, Any]]:
-    return [e for e in trace.get("traceEvents", ())
-            if e.get("ph") == "X" and e.get("cat") == "exchange"]
-
-
-def _interval_subtract(lo: float, hi: float,
-                       cover: Sequence[Tuple[float, float]]) -> float:
-    """Length of [lo, hi] NOT covered by the union of ``cover``."""
-    exposed = hi - lo
-    merged: List[List[float]] = []
-    for a, b in sorted(cover):
-        a, b = max(a, lo), min(b, hi)
-        if b <= a:
-            continue
-        if merged and a <= merged[-1][1]:
-            merged[-1][1] = max(merged[-1][1], b)
+def _merge(iv: Iterable[Tuple[int, int]]) -> Intervals:
+    out: List[List[int]] = []
+    for s, e in sorted(iv):
+        if out and s <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], e)
         else:
-            merged.append([a, b])
-    for a, b in merged:
-        exposed -= b - a
-    return max(exposed, 0.0)
+            out.append([s, e])
+    return [(s, e) for s, e in out]
 
 
-def summarize_trace(trace: Dict[str, Any]) -> Dict[str, Any]:
-    """Per-stage phase durations + exposed/hidden comm, averaged over
-    workers; plus step wall time when step slices are present."""
-    other = trace.get("otherData", {})
-    names = list(other.get("stage_names", ()))
-    slices = _slices(trace)
-    workers = sorted({e["pid"] for e in slices})
-    per_stage: Dict[str, Dict[str, Any]] = {
-        n: {"phase_us": {}, "collective_us": 0.0, "exposed_us": 0.0,
-            "hidden_us": 0.0} for n in names}
-    for w in workers:
-        mine = [e for e in slices if e["pid"] == w]
-        compute = [(e["ts"], e["ts"] + e["dur"]) for e in mine
-                   if e["name"] != "collective"]
-        for e in mine:
-            stage = e.get("args", {}).get("stage")
-            if stage not in per_stage:
-                continue
-            row = per_stage[stage]
-            row["phase_us"][e["name"]] = (
-                row["phase_us"].get(e["name"], 0.0) + e["dur"])
-            if e["name"] == "collective":
-                lo, hi = e["ts"], e["ts"] + e["dur"]
-                exp = _interval_subtract(lo, hi, compute)
-                row["collective_us"] += e["dur"]
-                row["exposed_us"] += exp
-                row["hidden_us"] += e["dur"] - exp
-    nw = max(len(workers), 1)
-    for row in per_stage.values():
-        row["phase_us"] = {k: v / nw for k, v in row["phase_us"].items()}
-        for k in ("collective_us", "exposed_us", "hidden_us"):
-            row[k] /= nw
-    steps = [e for e in trace.get("traceEvents", ())
-             if e.get("ph") == "X" and e.get("cat") == "step"]
-    step_us = (sum(e["dur"] for e in steps) / max(len(steps), 1)
-               if steps else None)
-    return {"stages": per_stage, "n_workers_traced": len(workers),
-            "step_us": step_us, "mode": other.get("mode"),
-            "codec": other.get("codec"), "backend": other.get("backend")}
+def _length(iv: Intervals) -> int:
+    return sum(e - s for s, e in iv)
 
 
-def predicted_vs_measured(trace: Dict[str, Any]) -> List[Dict[str, Any]]:
+def _intersect(a: Intervals, b: Intervals) -> Intervals:
+    out, i, j = [], 0, 0
+    while i < len(a) and j < len(b):
+        s, e = max(a[i][0], b[j][0]), min(a[i][1], b[j][1])
+        if e > s:
+            out.append((s, e))
+        if a[i][1] < b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return out
+
+
+def _gaps(busy: Intervals, lo: int, hi: int) -> Intervals:
+    """The parts of [lo, hi) that ``busy`` (merged) leaves uncovered."""
+    out, t = [], lo
+    for s, e in busy:
+        if s > t:
+            out.append((t, min(s, hi)))
+        t = max(t, e)
+    if hi > t:
+        out.append((t, hi))
+    return [(s, e) for s, e in out if e > s]
+
+
+def _device_ops(prof: Profile, device: int):
+    return [o for o in prof.ops if o.device == device]
+
+
+def _leaves(ops) -> list:
+    """Ops that enclose no other op (a ``while`` keeps its body's ops)."""
+    ops = sorted(ops, key=lambda o: (o.start, -o.end))
+    parent = set()
+    stack: list = []
+    for k, o in enumerate(ops):
+        while stack and ops[stack[-1]].end <= o.start:
+            stack.pop()
+        if stack and o.end <= ops[stack[-1]].end:
+            parent.add(stack[-1])
+        stack.append(k)
+    return [o for k, o in enumerate(ops) if k not in parent]
+
+
+def _layer(path: str) -> Optional[str]:
+    """The innermost layer scope (or the exchange) an op lies under."""
+    hits = [(path.rfind(s), s) for s in LAYERS if hooks.in_scope(path, s)]
+    return max(hits)[1] if hits else None
+
+
+def stage_timings(prof: Profile, names: Sequence[str]
+                  ) -> Dict[str, Dict[str, float]]:
+    """Per exchange stage: measured, exposed and hidden device µs per
+    step, averaged over chips."""
+    out = {n: {"collective_us": 0.0, "exposed_us": 0.0, "hidden_us": 0.0}
+           for n in names}
+    devs = prof.devices()
+    for d in devs:
+        ops = _device_ops(prof, d)
+        leaves = _leaves(ops)
+        for n in names:
+            mine = _merge((o.start, o.end) for o in ops
+                          if hooks.in_scope(o.path, n))
+            rest = _merge((o.start, o.end) for o in leaves
+                          if not hooks.in_scope(o.path, n))
+            meas = _length(mine)
+            exp = meas - _length(_intersect(mine, rest))
+            row = out[n]
+            row["collective_us"] += meas
+            row["exposed_us"] += exp
+            row["hidden_us"] += meas - exp
+    scale = 1e3 * max(len(devs), 1) * prof.steps()
+    for row in out.values():
+        for k in row:
+            row[k] /= scale
+    return out
+
+
+def layer_split(prof: Profile) -> Dict[str, float]:
+    """Per step, device ms of each layer scope (``hooks.LAYER_SCOPES``)
+    and of the exchange over the ops that enclose no other op, each
+    counted under its innermost scope (``model/layers`` keeps the layer
+    scan's own ops); ``other``, such ops under none of them; ``loop``,
+    the own time of enclosing ops (a ``while`` beyond its body's ops:
+    loop control); and ``idle``, averaged over chips."""
+    out = dict.fromkeys(LAYERS, 0.0)
+    devs = prof.devices()
+    other = loop = idle = 0.0
+    for d in devs:
+        ops = _device_ops(prof, d)
+        leaves = _leaves(ops)
+        layer = {o: _layer(o.path) for o in leaves}
+        for s in LAYERS:
+            out[s] += _length(_merge((o.start, o.end) for o in leaves
+                                     if layer[o] == s))
+        busy = _merge((o.start, o.end) for o in ops)
+        work = _merge((o.start, o.end) for o in leaves)
+        scoped = _merge((o.start, o.end) for o in leaves if layer[o])
+        other += _length(work) - _length(scoped)
+        loop += _length(busy) - _length(work)
+        idle += _length(_gaps(busy, busy[0][0], busy[-1][1]))
+    out.update(other=other, loop=loop, idle=idle)
+    scale = 1e6 * max(len(devs), 1) * prof.steps()
+    return {k: v / scale for k, v in out.items()}
+
+
+def idle_by_span(prof: Profile) -> Dict[str, float]:
+    """Share (%) of the capture window in which the device is idle while
+    the host is inside each Trainer span, averaged over chips."""
+    out = {n: 0.0 for n in hooks.TRAINER_SPANS}
+    devs = prof.devices()
+    for d in devs:
+        busy = _merge((o.start, o.end) for o in _device_ops(prof, d))
+        lo, hi = busy[0][0], busy[-1][1]
+        idle = _gaps(busy, lo, hi)
+        for n in out:
+            host = _merge((s.start, s.end) for s in prof.spans
+                          if s.name == n)
+            out[n] += 100.0 * _length(_intersect(idle, host)) / (hi - lo)
+    return {n: v / max(len(devs), 1) for n, v in out.items()}
+
+
+def predicted_vs_measured(prof: Profile) -> List[Dict[str, Any]]:
     """One row per schedule stage: the tuner's predicted µs, the
-    measured collective µs (worker-averaged), exposed/hidden split,
-    planned vs runtime-measured wire bytes, and the drift ratios that
-    close the loop ``dryrun --audit-exchange`` only checks statically."""
-    other = trace.get("otherData", {})
-    names = list(other.get("stage_names", ()))
-    summary = summarize_trace(trace)["stages"]
-    predicted = other.get("predicted_us", {})
-    planned_wire = other.get("planned_wire_bytes", {})
-    measured_wire = other.get("measured_wire_bytes", {})
+    measured device µs per step with its exposed/hidden split, planned
+    vs runtime-measured wire bytes, and the drift ratios that close the
+    loop ``dryrun --audit-exchange`` only checks statically."""
+    meta = prof.meta
+    names = list(meta.get("stage_names", ()))
+    timings = stage_timings(prof, names) if prof.ops else {}
+    return stage_rows(meta, timings)
+
+
+def stage_rows(meta: Dict[str, Any],
+               timings: Optional[Dict[str, Dict[str, float]]] = None
+               ) -> List[Dict[str, Any]]:
+    """Rows of ``predicted_vs_measured`` from an ``exchange.json``
+    block; without ``timings`` (the dry-run's wire leg) the time
+    columns are None."""
+    predicted = meta.get("predicted_us", {})
+    planned_wire = meta.get("planned_wire_bytes", {})
+    measured_wire = meta.get("measured_wire_bytes", {})
     rows = []
-    for n in names:
-        s = summary.get(n, {})
-        meas_us = s.get("collective_us", 0.0)
+    for n in meta.get("stage_names", ()):
+        t = (timings or {}).get(n, {})
+        meas_us = t.get("collective_us")
         pred_us = predicted.get(n)
         pw = planned_wire.get(n)
         mw = measured_wire.get(n)
@@ -113,10 +201,10 @@ def predicted_vs_measured(trace: Dict[str, Any]) -> List[Dict[str, Any]]:
             "stage": n,
             "predicted_us": pred_us,
             "measured_us": meas_us,
-            "exposed_us": s.get("exposed_us", 0.0),
-            "hidden_us": s.get("hidden_us", 0.0),
+            "exposed_us": t.get("exposed_us"),
+            "hidden_us": t.get("hidden_us"),
             "us_ratio": (meas_us / pred_us
-                         if pred_us not in (None, 0) else None),
+                         if pred_us and meas_us is not None else None),
             "planned_wire_bytes": pw,
             "measured_wire_bytes": mw,
             "wire_ratio": (mw / pw if pw and mw is not None else
@@ -132,6 +220,31 @@ def wire_exact(rows: Sequence[Dict[str, Any]]) -> bool:
                and abs(r["wire_ratio"] - 1.0) < 1e-9 for r in rows)
 
 
+def summarize_profile(trace_dir: str) -> Dict[str, Any]:
+    """The report of one ``--trace-dir``: per-stage rows, the per-step
+    split by layer, idle by Trainer span, and the plan's labels."""
+    prof = load_profile(trace_dir)
+    meta = prof.meta
+    rows = predicted_vs_measured(prof)
+    devs = prof.devices()
+    win = [max(o.end for o in _device_ops(prof, d))
+           - min(o.start for o in _device_ops(prof, d)) for d in devs]
+    return {
+        "n_stages": len(rows),
+        "stage_names": list(meta.get("stage_names", ())),
+        "mode": meta.get("mode"), "codec": meta.get("codec"),
+        "backend": meta.get("backend"),
+        "n_workers_traced": len(devs),
+        "n_steps_traced": prof.steps(),
+        "step_us": (sum(win) / len(win) / 1e3 / prof.steps()
+                    if win else None),
+        "wire_exact": wire_exact(rows),
+        "layers_ms": layer_split(prof) if devs else {},
+        "idle_share_by_span": idle_by_span(prof) if devs else {},
+        "rows": rows,
+    }
+
+
 def render_table(rows: Sequence[Dict[str, Any]]) -> str:
     """Fixed-width predicted-vs-measured table."""
     hdr = (f"{'stage':<52} {'pred_us':>9} {'meas_us':>9} {'exp_us':>8} "
@@ -139,7 +252,8 @@ def render_table(rows: Sequence[Dict[str, Any]]) -> str:
     lines = [hdr, "-" * len(hdr)]
 
     def fmt(v, spec):
-        return format(v, spec) if v is not None else "-"
+        width = int(spec.rstrip("df").split(".")[0])
+        return format(v, spec) if v is not None else "-".rjust(width)
 
     for r in rows:
         mw = r["measured_wire_bytes"]

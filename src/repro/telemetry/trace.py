@@ -1,48 +1,42 @@
-"""Step tracing: host-timestamp taps, wire measurement, Chrome export.
+"""Wire measurement and the profiler trace of the real training loop.
 
-Three tools, all built on the hook points in ``telemetry.hooks``:
+Two tools, neither of which changes the compiled program:
 
 * ``measure_wire(fn, *args)`` — run ONE abstract evaluation
   (``jax.eval_shape``) of an exchange program with a ``WireRecorder``
   installed.  Every collective call site in ``core/comm.py`` /
   ``core/backend.py`` bills its per-worker wire bytes (using the same
   per-hop formulas as the plan's static accounting) to the enclosing
-  stage scope.  Nothing executes and nothing is added to the real
-  program — this is the runtime drift detector for what
-  ``dryrun --audit-exchange`` checks against lowered HLO.
+  stage scope.  Nothing executes — this is the runtime drift detector
+  for what ``dryrun --audit-exchange`` checks against lowered HLO.
+  ``plan_trace_meta`` writes it, with the plan's stage names, wire
+  accounting and the tuner's predicted per-stage cost, into
+  ``exchange.json`` beside a profile.
 
-* ``StepTracer`` — optional host-timestamp taps (``io_callback``,
-  unordered) at the phase boundaries the exchange already marks
-  (accumulate/pack/collective/unpack).  OFF by default: when no tracer
-  is installed, ``hooks.tap`` returns its argument untouched and the
-  lowered program is bit-for-bit the uninstrumented one.  Taps consume
-  a scalar slice of each phase's output, so a tap fires when (in
-  dataflow order) that phase's result exists — timestamps are
-  *approximate* phase-end markers, the Horovod-timeline fidelity
-  level, not a profiler.
-
-* ``chrome_trace(...)`` — convert tap events into Chrome-trace /
-  Perfetto JSON: one process per worker, one thread row per schedule
-  stage, one duration slice per phase, with the plan's stage names,
-  planned + measured wire bytes, and the tuner's predicted per-stage
-  cost embedded in ``otherData`` so ``trace_report`` needs no replay.
+* ``load_profile(dir)`` — read what ``Trainer.run`` wrote under
+  ``TrainerConfig(profile_dir=...)``: the ``jax.profiler`` capture of
+  the loop's last steps (``.xplane.pb``) and the compiled step's HLO
+  text.  Device ops come out per chip, each with the scope path that
+  ``jax.named_scope`` wrote into its HLO ``op_name`` metadata (the
+  ``telemetry.hooks`` vocabulary and the ``exchange/sNN/...`` stage
+  names); host spans come from the Python main thread, on the device
+  ops' clock.  ``telemetry.report`` reduces them.
 """
 from __future__ import annotations
 
-import functools
+import dataclasses
+import glob
 import json
-import time
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+import os
+import re
+from typing import Any, Callable, Dict, List, Optional
 
 import jax
-import jax.numpy as jnp
 
 from repro.telemetry import hooks
 
-#: phase-end markers in intra-stage order (the trace row anatomy)
-PHASES = ("accumulate", "pack", "collective", "unpack")
-
-TRACE_SCHEMA = 1
+EXCHANGE_META = "exchange.json"   # beside a profile: the plan's accounting
+STEP_HLO = "step.hlo.txt"         # beside a profile: op -> scope metadata
 
 
 # ---------------------------------------------------------------------------
@@ -69,197 +63,20 @@ def measure_wire(fn: Callable, *args) -> hooks.WireRecorder:
     return rec
 
 
-# ---------------------------------------------------------------------------
-# Host-timestamp taps
-# ---------------------------------------------------------------------------
-
-class StepTracer:
-    """Collects (worker, stage, phase, host-time) events from the
-    ``hooks.tap`` sites while installed.
-
-    ``axis_names`` are the mesh axes the traced step runs under; the
-    flat worker index is recomputed per tap via ``axis_index`` (falling
-    back to worker 0 when no axis is bound, e.g. taps outside
-    shard_map)."""
-
-    def __init__(self, axis_names: Sequence[str] = ()) -> None:
-        self.axis_names = tuple(axis_names)
-        self.events: List[Dict[str, Any]] = []
-        self.step_marks: List[Dict[str, float]] = []
-
-    # -- called from traced code (via hooks.tap) ----------------------------
-    def tap(self, phase: str, stage: Optional[str], value):
-        if not isinstance(value, jax.Array):
-            return value
-        from jax.experimental import io_callback
-        dep = (value.ravel()[0] if value.size
-               else jnp.zeros((), value.dtype))
-        cb = functools.partial(self._record, stage or "", phase)
-        io_callback(cb, None, self._worker_id(), dep, ordered=False)
-        return value
-
-    def _worker_id(self):
-        flat = None
-        for a in self.axis_names:
-            try:
-                idx = jax.lax.axis_index(a)
-            except NameError:           # axis not bound here
-                continue
-            p = jax.lax.psum(1, a)
-            flat = idx if flat is None else flat * p + idx
-        return jnp.zeros((), jnp.int32) if flat is None else flat
-
-    def _record(self, stage, phase, wid, dep) -> None:
-        self.events.append({"stage": str(stage), "phase": str(phase),
-                            "worker": int(wid),
-                            "t": time.perf_counter()})
-
-    # -- host-side step boundary markers ------------------------------------
-    def mark_step(self, t_start: float, t_end: float) -> None:
-        self.step_marks.append({"t_start": t_start, "t_end": t_end})
-
-    # -- capture ------------------------------------------------------------
-    def capture(self, fn: Callable, *args, warmup: bool = True):
-        """Run ``fn(*args)`` with this tracer installed (a fresh
-        ``jax.jit`` wrapper forces a retrace so the taps lower into the
-        program).  With ``warmup`` the first (compiling) run's events
-        are discarded and a second, timed run produces the trace.
-        Returns ``fn``'s outputs from the timed run."""
-        jax.clear_caches()   # see measure_wire: defeat cached inner traces
-        jitted = jax.jit(fn)
-        hooks.install_tracer(self)
-        try:
-            if warmup:
-                out = jitted(*args)
-                jax.block_until_ready(out)
-                self.events.clear()
-            t0 = time.perf_counter()
-            out = jitted(*args)
-            out = jax.block_until_ready(out)
-            self.mark_step(t0, time.perf_counter())
-            return out
-        finally:
-            hooks.clear_tracer()
-
-
-# ---------------------------------------------------------------------------
-# Chrome-trace export
-# ---------------------------------------------------------------------------
-
-def _phase_rank(phase: str) -> int:
-    try:
-        return PHASES.index(phase)
-    except ValueError:
-        return len(PHASES)
-
-
-def chrome_trace(events: Sequence[Dict[str, Any]],
-                 stage_names: Sequence[str],
-                 step_marks: Sequence[Dict[str, float]] = (),
-                 meta: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
-    """Build a Chrome-trace dict: pid = worker, tid = schedule row (one
-    per stage, in schedule order), "X" duration slices per phase.
-
-    Phase events are END markers; each slice spans from the previous
-    marker of the same (worker, stage) row — or the step start — to its
-    own timestamp."""
-    rows = {name: k for k, name in enumerate(stage_names)}
-    t_base = min([m["t_start"] for m in step_marks]
-                 + [e["t"] for e in events], default=0.0)
-
-    def us(t: float) -> float:
-        return (t - t_base) * 1e6
-
-    trace_events: List[Dict[str, Any]] = []
-    workers = sorted({e["worker"] for e in events})
-    for w in workers:
-        for name, row in sorted(rows.items(), key=lambda kv: kv[1]):
-            trace_events.append({
-                "ph": "M", "name": "thread_name", "pid": w, "tid": row,
-                "args": {"name": name}})
-        mine = sorted((e for e in events if e["worker"] == w),
-                      key=lambda e: (e["t"], _phase_rank(e["phase"])))
-        last_by_stage: Dict[str, float] = {}
-        step_start = min((m["t_start"] for m in step_marks),
-                         default=t_base)
-        for e in mine:
-            stage = e["stage"]
-            row = rows.get(stage)
-            if row is None:      # unknown stage (e.g. broadcast rows)
-                row = len(rows) + 1
-            start = last_by_stage.get(stage, step_start)
-            trace_events.append({
-                "ph": "X", "name": e["phase"], "cat": "exchange",
-                "pid": w, "tid": row,
-                "ts": us(start), "dur": max(us(e["t"]) - us(start), 0.0),
-                "args": {"stage": stage, "worker": w}})
-            last_by_stage[stage] = e["t"]
-    for m in step_marks:
-        for w in workers or [0]:
-            trace_events.append({
-                "ph": "X", "name": "step", "cat": "step", "pid": w,
-                "tid": len(rows), "ts": us(m["t_start"]),
-                "dur": us(m["t_end"]) - us(m["t_start"]), "args": {}})
-    other = {"schema": TRACE_SCHEMA, "stage_names": list(stage_names)}
-    if meta:
-        other.update(meta)
-    return {"traceEvents": trace_events, "displayTimeUnit": "ms",
-            "otherData": other}
-
-
-def write_trace(trace: Dict[str, Any], path: str) -> None:
-    with open(path, "w") as f:
-        json.dump(trace, f, indent=1)
-
-
-# ---------------------------------------------------------------------------
-# One-call capture for an exchange step
-# ---------------------------------------------------------------------------
-
-def capture_exchange_trace(plan, fn: Callable, args: Tuple,
-                           axis_names: Sequence[str],
-                           n_workers, profile: str = "ethernet",
-                           out_path: Optional[str] = None,
-                           extra_meta: Optional[Dict[str, Any]] = None
-                           ) -> Dict[str, Any]:
-    """Full capture for one exchange-bearing step ``fn(*args)``:
-
-    1. ``measure_wire`` — one abstract evaluation bills runtime wire
-       bytes per stage (against ``plan.stage_wire_bytes``);
-    2. ``StepTracer.capture`` — a warm-up compile with taps lowered in,
-       then one timed run producing host-timestamp phase events;
-    3. Chrome-trace assembly with the plan's names/accounting/predicted
-       costs embedded — written to ``out_path`` when given.
-
-    Returns the trace dict.  The session-default (untraced) ``fn``
-    compilation is untouched — the tracer jits a fresh wrapper."""
-    wire = measure_wire(fn, *args)
-    tracer = StepTracer(axis_names=axis_names)
-    tracer.capture(fn, *args)
-    meta = plan_trace_meta(plan, n_workers, profile=profile,
-                           measured=wire)
-    if extra_meta:
-        meta.update(extra_meta)
-    trace = chrome_trace(tracer.events, plan.stage_names(),
-                         tracer.step_marks, meta)
-    if out_path:
-        write_trace(trace, out_path)
-    return trace
-
-
 def plan_trace_meta(plan, n_workers, profile: str = "ethernet",
                     measured: Optional[hooks.WireRecorder] = None
                     ) -> Dict[str, Any]:
-    """Self-contained metadata block for a trace file: stage names, the
-    plan's per-stage wire accounting, the tuner's per-stage predicted
-    cost, and (when given) the wire bytes a ``measure_wire`` recorder
-    observed — everything ``trace_report`` needs without recompiling
-    the plan."""
+    """Self-contained metadata block for a profile directory: stage
+    names, the plan's per-stage wire accounting, the tuner's per-stage
+    predicted cost, and (when given) the wire bytes a ``measure_wire``
+    recorder observed — everything ``trace_report`` needs besides the
+    trace, without recompiling the plan."""
     names = plan.stage_names()
     stages = plan.schedule.stages
     planned = {n: int(plan.stage_wire_bytes(s, n_workers))
                for n, s in zip(names, stages)}
     meta: Dict[str, Any] = {
+        "stage_names": list(names),
         "n_workers": (list(n_workers)
                       if isinstance(n_workers, (list, tuple))
                       else n_workers),
@@ -285,3 +102,165 @@ def plan_trace_meta(plan, n_workers, profile: str = "ethernet",
         meta["measured_wire_bytes"] = {
             k: v for k, v in measured.stage_wire_bytes().items()}
     return meta
+
+
+def write_step_hlo(trace_dir: str, jit_step, args) -> str:
+    """Write the compiled step's HLO text into ``trace_dir``."""
+    path = os.path.join(trace_dir, STEP_HLO)
+    with open(path, "w") as f:
+        f.write(jit_step.lower(*args).compile().as_text())
+    return path
+
+
+def write_meta(meta: Dict[str, Any], trace_dir: str) -> str:
+    os.makedirs(trace_dir, exist_ok=True)
+    path = os.path.join(trace_dir, EXCHANGE_META)
+    with open(path, "w") as f:
+        json.dump(meta, f, indent=1)
+    return path
+
+
+def read_meta(trace_dir: str) -> Dict[str, Any]:
+    path = os.path.join(trace_dir, EXCHANGE_META)
+    if not os.path.exists(path):
+        return {}
+    with open(path) as f:
+        return json.load(f)
+
+
+# ---------------------------------------------------------------------------
+# The profiler's trace
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class Op:
+    device: int
+    name: str
+    start: int          # ns
+    end: int            # ns
+    path: str = ""      # the HLO op's op_name: its scope path
+
+
+@dataclasses.dataclass(frozen=True)
+class Span:
+    name: str
+    start: int
+    end: int
+
+
+@dataclasses.dataclass
+class Profile:
+    ops: List[Op]
+    spans: List[Span]
+    meta: Dict[str, Any]
+
+    def devices(self) -> List[int]:
+        return sorted({o.device for o in self.ops})
+
+    def steps(self) -> int:
+        """Steps in the capture: the Trainer's per-step host spans."""
+        return max(sum(s.name == hooks.STEP for s in self.spans), 1)
+
+
+_DEVICE_PLANE = re.compile(r"^/device:[A-Z]+:(\d+)")
+_HLO_INSTR = re.compile(r"^\s*(?:ROOT\s+)?(%[^\s=]+)\s*=(.*)$", re.M)
+_OP_NAME = re.compile(r'metadata=\{op_name="([^"]*)"')
+_OPERAND = re.compile(r"%[\w.\-]+")
+_OPCODE = re.compile(r"\s([a-z][\w\-]*)\(")
+_HLO_MODULE = re.compile(r"^HloModule ([^\s,]+)", re.M)
+# the Python main thread's lines: the profiler's Python tracer, named
+# for the interpreter ("python", "python3"), which holds the Trainer's
+# spans, and the thread's TraceMe line ("main/<tid>") of the runtime's
+_HOST_LINE = re.compile(r"^(python[\d.]*|main)\b")
+
+
+def hlo_scopes(hlo_text: str) -> Dict[str, str]:
+    """HLO instruction name -> its scope path, from a compiled module's
+    text: the ``op_name`` metadata that ``jax.named_scope`` writes.  An
+    instruction the compiler made without metadata (a layout copy, a
+    split reduction, a rewritten dot) takes the path of its first
+    operand that has one; failing that (a loop buffer's initial value)
+    the path of its first user, through tuples, which carry none of
+    their own."""
+    paths: Dict[str, str] = {}
+    operands: Dict[str, List[str]] = {}
+    tuples = set()
+    for m in _HLO_INSTR.finditer(hlo_text):
+        name, rest = m.groups()
+        meta = _OP_NAME.search(rest)
+        if meta:
+            paths[name] = meta.group(1)
+        head = rest.split(" metadata=")[0]
+        op = _OPCODE.search(head)
+        if op and op.group(1) == "tuple":
+            tuples.add(name)
+        operands[name] = [t for t in _OPERAND.findall(head)
+                          if t in operands]
+    for name, ins in operands.items():     # operands come first
+        if name not in paths and name not in tuples:
+            known = [paths[o] for o in ins if o in paths]
+            if known:
+                paths[name] = known[0]
+    users: Dict[str, List[str]] = {}
+    for name, ins in operands.items():
+        for o in ins:
+            users.setdefault(o, []).append(name)
+    for name in reversed(list(operands)):  # users come later
+        if name not in paths:
+            known = [paths[u] for u in users.get(name, ()) if u in paths]
+            if known:
+                paths[name] = known[0]
+    return paths
+
+
+def _xplane_files(trace_dir: str) -> List[str]:
+    """The newest capture's ``.xplane.pb`` files under ``trace_dir``."""
+    files = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    if not files:
+        raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
+    newest = max(os.path.dirname(f) for f in files)
+    return sorted(f for f in files if os.path.dirname(f) == newest)
+
+
+def load_profile(trace_dir: str) -> Profile:
+    """Device ops (a TPU's "XLA Ops" line; a CPU backend's ops of the
+    step's module on its host threads) and the host spans of the Python
+    main thread.  A TPU names an op by its HLO instruction; the scope
+    path comes from ``step.hlo.txt`` beside the capture."""
+    from jax.profiler import ProfileData
+    hlo_path = os.path.join(trace_dir, STEP_HLO)
+    hlo = open(hlo_path).read() if os.path.exists(hlo_path) else ""
+    scopes = hlo_scopes(hlo)
+    module = _HLO_MODULE.search(hlo)
+    module = module.group(1) if module else None
+    ops: List[Op] = []
+    spans: List[Span] = []
+    for f in _xplane_files(trace_dir):
+        for plane in ProfileData.from_file(f).planes:
+            dev = _DEVICE_PLANE.match(plane.name)
+            if not dev and not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                if dev and line.name != "XLA Ops":
+                    continue
+                for ev in line.events:
+                    start = int(ev.start_ns)
+                    end = start + int(ev.duration_ns)
+                    if dev:
+                        name = ev.name.split(" = ", 1)[0]
+                        ops.append(Op(int(dev.group(1)), name, start, end,
+                                      scopes.get(name, "")))
+                        continue
+                    st = {k: v for k, v in ev.stats}
+                    if "hlo_op" in st and "device_ordinal" in st:
+                        # a CPU backend runs its "device" ops here
+                        if module and st.get("hlo_module") not in (
+                                None, module):
+                            continue
+                        name = "%" + str(st["hlo_op"])
+                        ops.append(Op(int(st["device_ordinal"]), name,
+                                      start, end, scopes.get(name, "")))
+                    elif _HOST_LINE.match(line.name) and end > start:
+                        spans.append(Span(ev.name, start, end))
+    return Profile(ops, spans, read_meta(trace_dir))

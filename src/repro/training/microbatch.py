@@ -24,6 +24,7 @@ import jax.numpy as jnp
 from repro.training.gradients import (grad_contributions,
                                       wait_free_grad_exchange)
 from repro.core.indexed_slices import IndexedSlices
+from repro.telemetry import hooks as scopes
 
 
 def split_microbatches(batch: Dict[str, jax.Array], n: int
@@ -330,14 +331,16 @@ def make_scaled_train_step(model, opt, scaler: LossScaler,
                 dense, ex_state = opt.exchange(grads, state=ex_state)
         dense, finite, scaler_state = scaler.unscale_and_check(
             dense, scaler_state)
-        updates, new_opt_state = opt.base.update(dense, opt_state, params)
-        new_params = apply_updates(params, updates)
-        params = jax.tree_util.tree_map(
-            lambda new, old: jnp.where(finite, new, old),
-            new_params, params)
-        opt_state = jax.tree_util.tree_map(
-            lambda new, old: jnp.where(finite, new, old),
-            new_opt_state, opt_state)
+        with jax.named_scope(scopes.OPTIM):
+            updates, new_opt_state = opt.base.update(dense, opt_state,
+                                                     params)
+            new_params = apply_updates(params, updates)
+            params = jax.tree_util.tree_map(
+                lambda new, old: jnp.where(finite, new, old),
+                new_params, params)
+            opt_state = jax.tree_util.tree_map(
+                lambda new, old: jnp.where(finite, new, old),
+                new_opt_state, opt_state)
         if ex_state is not None:
             # an overflowed encode banks inf-inf = NaN residuals that
             # would poison every later step's wire

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 
 from repro.core.dist_opt import DistributedOptimizer
 from repro.optim.base import apply_updates
+from repro.telemetry import hooks as scopes
 from repro.training.gradients import (grad_contributions,
                                       wait_free_grad_exchange)
 
@@ -60,8 +61,9 @@ def make_train_step(model, opt: DistributedOptimizer,
             grads, loss, metrics = grad_contributions(
                 model, params, batch, sparse_embedding=sparse_embedding,
                 **loss_kw)
-            params, opt_state, ex_state = opt.zero1_step(
-                grads, params, opt_state, exchange_state=ex_state)
+            with jax.named_scope(scopes.OPTIM):
+                params, opt_state, ex_state = opt.zero1_step(
+                    grads, params, opt_state, exchange_state=ex_state)
             n_stages = opt.plan(grads).schedule.n_stages
             metrics = dict(metrics, loss=loss,
                            exchange_stages=jnp.int32(n_stages))
@@ -86,8 +88,9 @@ def make_train_step(model, opt: DistributedOptimizer,
             n_stages = opt.plan(grads).schedule.n_stages
             metrics = dict(metrics, loss=loss,
                            exchange_stages=jnp.int32(n_stages))
-        updates, opt_state = opt.base.update(dense, opt_state, params)
-        params = apply_updates(params, updates)
+        with jax.named_scope(scopes.OPTIM):
+            updates, opt_state = opt.base.update(dense, opt_state, params)
+            params = apply_updates(params, updates)
         return params, opt_state, ex_state, metrics
 
     if cfg is None:
@@ -95,8 +98,9 @@ def make_train_step(model, opt: DistributedOptimizer,
             grads, loss, metrics = grad_contributions(
                 model, params, batch, sparse_embedding=sparse_embedding,
                 **loss_kw)
-            updates, opt_state = opt.update(grads, opt_state, params)
-            params = apply_updates(params, updates)
+            with jax.named_scope(scopes.OPTIM):
+                updates, opt_state = opt.update(grads, opt_state, params)
+                params = apply_updates(params, updates)
             return params, opt_state, dict(metrics, loss=loss)
     elif stateful:
         def step(params, opt_state, ex_state, batch):

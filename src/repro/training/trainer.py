@@ -1,6 +1,7 @@
 """Trainer: the end-to-end loop (data -> step -> metrics -> checkpoint)."""
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Any, Callable, Dict, List, Optional
@@ -9,6 +10,8 @@ import jax
 import numpy as np
 
 from repro.checkpoint import save_checkpoint, restore_checkpoint, latest_step
+from repro.telemetry import hooks
+from repro.telemetry.trace import write_step_hlo
 
 
 @dataclasses.dataclass
@@ -18,6 +21,13 @@ class TrainerConfig:
     checkpoint_every: int = 0          # 0 disables
     checkpoint_dir: Optional[str] = None
     resume: bool = False
+    # profile the last ``profile_steps`` steps of ``run`` with
+    # ``jax.profiler`` into ``profile_dir`` (an .xplane.pb and a
+    # Perfetto trace under plugins/profile/, and the compiled step's
+    # HLO text, whose metadata names each device op's scope; read them
+    # with telemetry.trace.load_profile)
+    profile_dir: Optional[str] = None
+    profile_steps: int = 0
 
 
 @dataclasses.dataclass
@@ -70,67 +80,101 @@ class Trainer:
         t0 = time.perf_counter()
         window_t0, window_steps = t0, 0
         window_data_ms = 0.0
-        for step in range(start_step, cfg.total_steps):
-            if rec is not None:
-                rec.step_start()
-            t_fetch = time.perf_counter()
-            batch = {k: jax.device_put(v, self.batch_sharding)
-                     for k, v in self.pipeline.batch_at(step).items()}
-            data_ms = (time.perf_counter() - t_fetch) * 1e3
-            window_data_ms += data_ms
-            if rec is not None:
-                rec.data_loaded()
-            if stateful:
-                params, opt_state, exchange_state, metrics = jit_step(
-                    params, opt_state, exchange_state, batch)
-            else:
-                params, opt_state, metrics = jit_step(params, opt_state,
-                                                      batch)
-            # defer the device->host read of the loss-scaler overflow
-            # flag to the log boundary (no per-step sync on the default
-            # path); overflow steps are skipped updates (PR-5 rollback)
-            # and were silent before
-            if "overflow" in metrics:
-                overflow_pending.append(metrics["overflow"])
-            if rec is not None:
-                rec.step_end(metrics)
-            tokens_seen += int(np.prod(batch["tokens"].shape))
-            window_steps += 1
-            if (step + 1) % cfg.log_every == 0 or step == cfg.total_steps - 1:
-                m = {k: float(v) for k, v in metrics.items()
-                     if np.ndim(v) == 0}
-                now = time.perf_counter()
-                dt = now - t0
-                if overflow_pending:
-                    overflow_skipped += int(sum(
-                        int(np.asarray(o)) for o in overflow_pending))
-                    overflow_pending.clear()
-                # mean wall-time per step since the last log line (the
-                # number the overlap benchmark compares on/off), with
-                # the host data fetch split out
-                m.update(step=step + 1, tokens=tokens_seen,
-                         tok_per_s=tokens_seen / max(dt, 1e-9),
-                         step_ms=(now - window_t0) * 1e3
-                         / max(window_steps, 1),
-                         data_ms=window_data_ms / max(window_steps, 1),
-                         overflow_skipped=overflow_skipped)
-                window_t0, window_steps = now, 0
-                window_data_ms = 0.0
-                history.append(m)
-                skipped = (f" overflow_skipped={overflow_skipped}"
-                           if overflow_skipped else "")
-                log(f"step {step+1}: loss={m.get('loss', float('nan')):.4f} "
-                    f"ce={m.get('ce', float('nan')):.4f} "
-                    f"tok/s={m['tok_per_s']:.0f} "
-                    f"step_ms={m['step_ms']:.1f} "
-                    f"data_ms={m['data_ms']:.2f}{skipped}")
-                if rec is not None:
-                    rec.flush()
-            if (cfg.checkpoint_every and cfg.checkpoint_dir
-                    and (step + 1) % cfg.checkpoint_every == 0):
-                tree = ((params, opt_state, exchange_state) if stateful
-                        else (params, opt_state))
-                save_checkpoint(cfg.checkpoint_dir, step + 1, tree)
+        profile_from = (max(start_step, cfg.total_steps - cfg.profile_steps)
+                        if cfg.profile_dir and cfg.profile_steps > 0
+                        else None)
+        batch = None
+        with contextlib.ExitStack() as profiling:
+            for step in range(start_step, cfg.total_steps):
+                if step == profile_from:
+                    # start on an idle device, so that the capture holds
+                    # the device work of the profiled steps alone
+                    jax.block_until_ready(params)
+                    profiling.enter_context(jax.profiler.trace(
+                        cfg.profile_dir, create_perfetto_trace=True))
+                step_span = jax.profiler.StepTraceAnnotation(
+                    hooks.STEP, step_num=step)
+                with step_span:
+                    if rec is not None:
+                        rec.step_start()
+                    t_fetch = time.perf_counter()
+                    with jax.profiler.TraceAnnotation(hooks.FETCH):
+                        host = self.pipeline.batch_at(step)
+                    with jax.profiler.TraceAnnotation(hooks.DEVICE_PUT):
+                        batch = {k: jax.device_put(v, self.batch_sharding)
+                                 for k, v in host.items()}
+                    data_ms = (time.perf_counter() - t_fetch) * 1e3
+                    window_data_ms += data_ms
+                    if rec is not None:
+                        rec.data_loaded()
+                    with jax.profiler.TraceAnnotation(hooks.DISPATCH):
+                        if stateful:
+                            params, opt_state, exchange_state, metrics = \
+                                jit_step(params, opt_state, exchange_state,
+                                         batch)
+                        else:
+                            params, opt_state, metrics = jit_step(
+                                params, opt_state, batch)
+                    # defer the device->host read of the loss-scaler
+                    # overflow flag to the log boundary (no per-step sync
+                    # on the default path); overflow steps are skipped
+                    # updates (the scaler rolls the state back)
+                    if "overflow" in metrics:
+                        overflow_pending.append(metrics["overflow"])
+                    if rec is not None:
+                        rec.step_end(metrics)
+                    tokens_seen += int(np.prod(batch["tokens"].shape))
+                    window_steps += 1
+                    if ((step + 1) % cfg.log_every == 0
+                            or step == cfg.total_steps - 1):
+                        with jax.profiler.TraceAnnotation(hooks.LOG):
+                            m = {k: float(v) for k, v in metrics.items()
+                                 if np.ndim(v) == 0}
+                            now = time.perf_counter()
+                            dt = now - t0
+                            if overflow_pending:
+                                overflow_skipped += int(sum(
+                                    int(np.asarray(o))
+                                    for o in overflow_pending))
+                                overflow_pending.clear()
+                            # mean wall-time per step since the last log
+                            # line (the number the overlap benchmark
+                            # compares on/off), with the host data fetch
+                            # split out
+                            m.update(step=step + 1, tokens=tokens_seen,
+                                     tok_per_s=tokens_seen / max(dt, 1e-9),
+                                     step_ms=(now - window_t0) * 1e3
+                                     / max(window_steps, 1),
+                                     data_ms=window_data_ms
+                                     / max(window_steps, 1),
+                                     overflow_skipped=overflow_skipped)
+                            window_t0, window_steps = now, 0
+                            window_data_ms = 0.0
+                            history.append(m)
+                            skipped = (f" overflow_skipped={overflow_skipped}"
+                                       if overflow_skipped else "")
+                            log(f"step {step+1}: "
+                                f"loss={m.get('loss', float('nan')):.4f} "
+                                f"ce={m.get('ce', float('nan')):.4f} "
+                                f"tok/s={m['tok_per_s']:.0f} "
+                                f"step_ms={m['step_ms']:.1f} "
+                                f"data_ms={m['data_ms']:.2f}{skipped}")
+                            if rec is not None:
+                                rec.flush()
+                    if (cfg.checkpoint_every and cfg.checkpoint_dir
+                            and (step + 1) % cfg.checkpoint_every == 0):
+                        with jax.profiler.TraceAnnotation(hooks.CHECKPOINT):
+                            tree = ((params, opt_state, exchange_state)
+                                    if stateful else (params, opt_state))
+                            save_checkpoint(cfg.checkpoint_dir, step + 1,
+                                            tree)
+            if profile_from is not None:
+                # the last step's device work belongs in the profile
+                jax.block_until_ready(params)
+        if profile_from is not None and batch is not None:
+            args = ((params, opt_state, exchange_state, batch) if stateful
+                    else (params, opt_state, batch))
+            write_step_hlo(cfg.profile_dir, jit_step, args)
         if rec is not None:
             rec.flush()
         return {"params": params, "opt_state": opt_state,

@@ -1,7 +1,9 @@
 """Telemetry: stage annotation names, runtime wire counters vs the
-plan's accounting, Chrome-trace validity + the trace_report round-trip,
-metrics JSONL schema stability, and the disabled-path guarantees
-(``hooks.tap`` is the identity, instrumentation adds zero collectives).
+plan's accounting, the layer scopes in the compiled step, the Trainer's
+host spans and the profiler capture of the real loop (bitwise-inert,
+reported per layer and per stage by scripts/trace_report.py), metrics
+JSONL schema stability, and the disabled-path guarantees (no host
+callbacks, instrumentation adds zero collectives).
 
 Multi-device cases run in subprocesses with 8 emulated CPU workers,
 like test_exchange.py / test_wait_free.py."""
@@ -21,6 +23,7 @@ from repro.telemetry import hooks
 from repro.telemetry import metrics as metrics_lib
 from repro.telemetry import report as report_lib
 from repro.telemetry import trace as trace_lib
+from repro.telemetry.trace import Op, Profile, Span
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -88,13 +91,6 @@ def test_stage_name_index_lookup():
 # ---------------------------------------------------------------------------
 # Hooks: disabled path is inert
 # ---------------------------------------------------------------------------
-
-def test_tap_identity_when_disabled():
-    x = jnp.arange(4.0)
-    assert hooks.tap("pack", x) is x
-    assert hooks.tracer() is None
-    assert hooks.wire_recorder() is None
-
 
 def test_stage_scope_nesting():
     assert hooks.current_stage() is None
@@ -275,124 +271,283 @@ print("STATEFUL-OK")
 
 
 # ---------------------------------------------------------------------------
-# Trace capture: Chrome validity, bitwise identity, report round-trip
+# Layer scopes in the compiled step
 # ---------------------------------------------------------------------------
 
-def test_capture_trace_valid_and_bitwise(tmp_path):
-    """An instrumented capture (a) produces a Chrome trace with one row
-    set per schedule stage and wire exactly matching the plan, and (b)
-    returns outputs BITWISE identical to the untraced execution — taps
-    are identity ops."""
-    out_json = tmp_path / "trace.json"
-    code = r"""
-import jax, numpy as np, json
-jax.config.update("jax_platform_name", "cpu")
-import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
-from jax import shard_map
-from repro.core import exchange
-from repro.telemetry import trace as trace_lib
+def _reduced_model():
+    from repro.configs import get_config
+    from repro.models import build_model
 
-g = {"a": jnp.arange(1024, dtype=jnp.float32).reshape(32, 32),
-     "b": jnp.ones((17,), jnp.float32)}
-plan = exchange.compile_plan(g, exchange.ExchangeConfig(
-    sparse_as_dense=True, codec="int8", overlap=True))
-mesh = Mesh(np.array(jax.devices()), ("data",))
-sm = shard_map(lambda gg: plan.execute(gg, "data"), mesh=mesh,
-               in_specs=(P(),), out_specs=P(), check_vma=False)
-base = jax.jit(sm)(g)
-trace = trace_lib.capture_exchange_trace(
-    plan, sm, (g,), ("data",), 8, out_path=OUT)
-traced_out = trace_lib.StepTracer(("data",)).capture(sm, g)
-for x, y in zip(jax.tree_util.tree_leaves(base),
-                jax.tree_util.tree_leaves(traced_out)):
-    assert x.dtype == y.dtype and bool(jnp.array_equal(x, y))
-after = jax.jit(sm)(g)
-for x, y in zip(jax.tree_util.tree_leaves(base),
-                jax.tree_util.tree_leaves(after)):
-    assert bool(jnp.array_equal(x, y))
-print("BITWISE-OK")
-""".replace("OUT", repr(str(out_json)))
-    out = run_with_devices(code)
-    assert "BITWISE-OK" in out
-
-    trace = report_lib.load_trace(str(out_json))
-    assert trace["otherData"]["schema"] == trace_lib.TRACE_SCHEMA
-    names = trace["otherData"]["stage_names"]
-    evs = [e for e in trace["traceEvents"] if e.get("ph") == "X"]
-    for e in evs:   # structurally valid Chrome events
-        assert {"name", "pid", "tid", "ts", "dur"} <= set(e)
-        assert e["dur"] >= 0
-    stages_seen = {e["args"]["stage"] for e in evs
-                   if e.get("cat") == "exchange"}
-    assert stages_seen == set(names)
-    collected = {e["args"]["stage"] for e in evs
-                 if e.get("cat") == "exchange"
-                 and e["name"] == "collective"}
-    assert collected == set(names)
-
-    rows = report_lib.predicted_vs_measured(trace)
-    assert [r["stage"] for r in rows] == names
-    assert report_lib.wire_exact(rows)
-    summary = report_lib.summarize_trace(trace)
-    assert summary["n_workers_traced"] == 8
-    assert set(summary["stages"]) == set(names)
+    cfg = get_config("transformer-big").reduced()
+    model = build_model(cfg)
+    return cfg, model, model.init(jax.random.PRNGKey(0))
 
 
-def test_trace_report_cli(tmp_path):
-    """scripts/trace_report.py round-trips a synthetic trace."""
-    events = [{"stage": "exchange/s00/allreduce/bucket=dense0",
-               "phase": ph, "worker": w, "t": 0.001 * (k + 1)}
-              for w in (0, 1)
-              for k, ph in enumerate(trace_lib.PHASES)]
-    trace = trace_lib.chrome_trace(
-        events, ["exchange/s00/allreduce/bucket=dense0"],
-        [{"t_start": 0.0, "t_end": 0.01}],
-        meta={"planned_wire_bytes":
-              {"exchange/s00/allreduce/bucket=dense0": 100},
-              "measured_wire_bytes":
-              {"exchange/s00/allreduce/bucket=dense0": 100},
-              "predicted_us":
-              {"exchange/s00/allreduce/bucket=dense0": 123.0}})
-    path = tmp_path / "t.json"
-    trace_lib.write_trace(trace, str(path))
+def _exchange_opt():
+    from repro.core import DistributedOptimizer, ExchangeConfig
+    from repro.optim import adamw
+
+    return DistributedOptimizer(adamw(1e-3), exchange=ExchangeConfig(
+        sparse_as_dense=True))
+
+
+@pytest.fixture(scope="module")
+def step_hlo_paths():
+    """op_name paths of the compiled reduced transformer-big step (dense
+    autodiff of the embedding, so its lookup has a backward too)."""
+    from repro.data import make_pipeline
+    from repro.training import make_train_step
+
+    cfg, model, params = _reduced_model()
+    opt = _exchange_opt()
+    step = make_train_step(model, opt)
+    batch = {k: jnp.asarray(v)
+             for k, v in make_pipeline(cfg, 2, 16).batch_at(0).items()}
+    txt = jax.jit(step).lower(params, opt.init(params),
+                              batch).compile().as_text()
+    return re.findall(r'op_name="([^"]*)"', txt)
+
+
+@pytest.mark.parametrize("scope", hooks.LAYER_SCOPES)
+def test_layer_scope_in_compiled_step(step_hlo_paths, scope):
+    """Each layer scope names ops of the compiled step, forward and
+    differentiated (``transpose(...)``); the optimizer is never
+    differentiated."""
+    mine = [p for p in step_hlo_paths if hooks.in_scope(p, scope)]
+    forward = [p for p in mine if "transpose(" not in p]
+    backward = [p for p in mine if "transpose(" in p]
+    assert forward, scope
+    if scope == hooks.OPTIM:
+        assert not backward, backward[:3]
+    else:
+        assert backward, scope
+    others = [s for s in hooks.LAYER_SCOPES if s != scope]
+    assert not any(scope in s for s in others)   # no name inside another
+
+
+def test_step_has_no_host_callback():
+    """With no profile directory (the default), the lowered step holds
+    no host callback: spans and scopes add nothing to the program."""
+    from repro.data import make_pipeline
+    from repro.training import make_train_step
+
+    cfg, model, params = _reduced_model()
+    opt = _exchange_opt()
+    step = make_train_step(model, opt, sparse_embedding=True)
+    batch = {k: jnp.asarray(v)
+             for k, v in make_pipeline(cfg, 2, 16).batch_at(0).items()}
+    callback = re.compile(r"custom[_-]call\b[^\n]*callback")
+    lowered = jax.jit(step).lower(params, opt.init(params), batch)
+    for txt in (lowered.as_text(), lowered.compile().as_text()):
+        assert not callback.search(txt), callback.search(txt).group(0)
+    # the pattern finds a host callback where there is one
+    from jax.experimental import io_callback
+    tapped = jax.jit(lambda x: io_callback(lambda v: v, x, x)).lower(
+        jnp.ones(3)).compile().as_text()
+    assert callback.search(tapped)
+
+
+# ---------------------------------------------------------------------------
+# The profiler capture of the real loop
+# ---------------------------------------------------------------------------
+
+def _run_trainer(profile_dir, ckpt_dir, steps=3, log_every=1, profile_steps=2):
+    """Reduced transformer-big through ``Trainer.run``; with a
+    ``ckpt_dir`` every step logs and saves a checkpoint, so that it
+    holds all five Trainer spans."""
+    from repro.data import make_pipeline
+    from repro.training import Trainer, TrainerConfig, make_train_step
+
+    cfg, model, params = _reduced_model()
+    opt = _exchange_opt()
+    step = make_train_step(model, opt, sparse_embedding=True)
+    tr = Trainer(model, step, make_pipeline(cfg, 2, 16), TrainerConfig(
+        total_steps=steps, log_every=log_every,
+        checkpoint_every=int(ckpt_dir is not None),
+        checkpoint_dir=None if ckpt_dir is None else str(ckpt_dir),
+        profile_dir=None if profile_dir is None else str(profile_dir),
+        profile_steps=profile_steps))
+    res = tr.run(params, opt.init(params), log=lambda s: None)
+    return res, opt, step, params
+
+
+@pytest.fixture(scope="module")
+def profiled_run(tmp_path_factory):
+    base = tmp_path_factory.mktemp("profiled")
+    res, opt, step, params = _run_trainer(base / "trace", base / "ckpt")
+    return base / "trace", res, opt, step, params
+
+
+def test_trainer_spans_in_order_and_bitwise(profiled_run, tmp_path):
+    """The profiled steps carry the five Trainer spans in order inside
+    their step span, and the run's params equal, bit for bit, those of
+    the same run without the profiler."""
+    trace_dir, res, _, _, _ = profiled_run
+    prof = trace_lib.load_profile(str(trace_dir))
+    steps = sorted((s for s in prof.spans if s.name == hooks.STEP),
+                   key=lambda s: s.start)
+    assert len(steps) == 2
+    for st in steps:
+        inside = sorted((s for s in prof.spans
+                         if s.name in hooks.TRAINER_SPANS
+                         and st.start <= s.start and s.end <= st.end),
+                        key=lambda s: s.start)
+        assert [s.name for s in inside] == list(hooks.TRAINER_SPANS)
+    assert (trace_dir / trace_lib.STEP_HLO).exists()
+    assert list(trace_dir.glob("plugins/profile/*/perfetto_trace.json.gz"))
+
+    plain, _, _, _ = _run_trainer(None, tmp_path / "ckpt")
+    for x, y in zip(jax.tree_util.tree_leaves(res["params"]),
+                    jax.tree_util.tree_leaves(plain["params"])):
+        assert x.dtype == y.dtype and bool(jnp.array_equal(x, y))
+
+
+def test_layer_scopes_cover_device_time(tmp_path):
+    """The layer scopes plus the exchange's cover at least 90% of the
+    device's busy time per step of the reduced step on the CPU: of the
+    time its ops do work, in a run that syncs only at its end, as a
+    training run does between log boundaries.  The loop control of an
+    enclosing ``while`` (on the CPU backend, thread hand-offs between
+    its body's ops, which stretch under load) is reported apart."""
+    _run_trainer(tmp_path / "trace", None, steps=4, log_every=4)
+    split = report_lib.layer_split(
+        trace_lib.load_profile(str(tmp_path / "trace")))
+    scoped = sum(v for k, v in split.items()
+                 if k not in ("other", "loop", "idle"))
+    assert split[hooks.FFN] > 0 and split[hooks.OPTIM] > 0
+    assert split["loop"] >= 0
+    assert scoped >= 0.9 * (scoped + split["other"]), split
+
+
+def test_trace_report_cli(profiled_run):
+    """scripts/trace_report.py reads a profile directory: one row per
+    schedule stage with device time, the layer split, wire exact."""
+    from repro.data import make_pipeline
+    from repro.training.gradients import abstract_grad_contributions
+
+    trace_dir, _, opt, step, params = profiled_run
+    cfg, model, _ = _reduced_model()
+    batch = {k: jnp.asarray(v)
+             for k, v in make_pipeline(cfg, 2, 16).batch_at(0).items()}
+    plan = opt.plan(abstract_grad_contributions(model, params, batch,
+                                                sparse_embedding=True))
+    wire = trace_lib.measure_wire(step, params, opt.init(params), batch)
+    trace_lib.write_meta(trace_lib.plan_trace_meta(plan, 1, measured=wire),
+                         str(trace_dir))
 
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "scripts", "trace_report.py"),
-         str(path), "--json"],
-        env=env, capture_output=True, text=True, timeout=120)
+    script = os.path.join(REPO, "scripts", "trace_report.py")
+    out = subprocess.run([sys.executable, script, str(trace_dir), "--json"],
+                         env=env, capture_output=True, text=True,
+                         timeout=300)
     assert out.returncode == 0, out.stderr[-2000:]
     d = json.loads(out.stdout)
-    assert d["n_stages"] == 1 and d["wire_exact"] is True
-    assert d["rows"][0]["predicted_us"] == 123.0
-    assert d["rows"][0]["measured_us"] > 0
+    assert d["n_stages"] == len(d["stage_names"]) == len(d["rows"]) > 0
+    assert d["wire_exact"] is True and d["n_workers_traced"] == 1
+    assert d["n_steps_traced"] == 2
+    assert set(hooks.LAYER_SCOPES) <= set(d["layers_ms"])
+    assert set(d["idle_share_by_span"]) == set(hooks.TRAINER_SPANS)
+    for r in d["rows"]:
+        assert r["predicted_us"] is not None
+        assert r["exposed_us"] + r["hidden_us"] == \
+            pytest.approx(r["measured_us"])
+    table = subprocess.run([sys.executable, script, str(trace_dir)],
+                           env=env, capture_output=True, text=True,
+                           timeout=300)
+    assert table.returncode == 0 and "wire exact vs plan: True" in \
+        table.stdout
+    empty = subprocess.run([sys.executable, script, str(trace_dir / "nope")],
+                           env=env, capture_output=True, text=True,
+                           timeout=300)
+    assert empty.returncode == 2
+
+
+def test_capture_trace_valid_and_bitwise(tmp_path):
+    """``train.py --trace-dir`` on 8 workers profiles the real loop's
+    last steps: every schedule stage has device time on all 8 chips and
+    wire exactly matching the plan, and the run's params are BITWISE
+    those of the same run without the profile."""
+    out_dir = tmp_path / "trace"
+    code = r"""
+import jax, numpy as np
+jax.config.update("jax_platform_name", "cpu")
+from repro.launch.train import train
+argv = ["--arch", "transformer-big", "--reduced", "--dist", "horovod",
+        "--overlap", "staged", "--codec", "int8", "--steps", "3",
+        "--batch-per-worker", "1", "--seq-len", "16", "--log-every", "3"]
+a = train(argv + ["--trace-dir", OUT])
+b = train(argv)
+for x, y in zip(jax.tree_util.tree_leaves(a["params"]),
+                jax.tree_util.tree_leaves(b["params"])):
+    assert x.dtype == y.dtype and np.array_equal(np.asarray(x),
+                                                 np.asarray(y))
+print("BITWISE-OK")
+""".replace("OUT", repr(str(out_dir)))
+    out = run_with_devices(code)
+    assert "BITWISE-OK" in out
+
+    s = report_lib.summarize_profile(str(out_dir))
+    names = s["stage_names"]
+    assert s["n_stages"] == len(names) > 0
+    assert [r["stage"] for r in s["rows"]] == names
+    assert s["wire_exact"] and s["n_workers_traced"] == 8
+    assert s["n_steps_traced"] == 2 and s["mode"] == "staged"
+    for r in s["rows"]:
+        assert r["measured_us"] > 0, r["stage"]
+    assert s["layers_ms"][hooks.EXCHANGE] > 0
+
+
+def test_hlo_scopes_name_ops_without_metadata():
+    """An op the compiler made without metadata takes its first
+    operand's scope, else (a loop buffer's initial value) its first
+    user's, through the tuple that feeds the loop."""
+    text = """
+  %constant.1 = f32[] constant(0)
+  %broadcast.2 = f32[4]{0} broadcast(%constant.1), dimensions={}
+  %copy.3 = f32[4]{0} copy(%broadcast.2)
+  %add.4 = f32[4]{0} add(%p.0, %p.0), metadata={op_name="jit(step)/jvp(model/embed)/add"}
+  %reduce-window.5 = f32[2]{0} reduce-window(%add.4, %constant.1), window={size=2}
+  %tuple.6 = (f32[4]{0}, f32[4]{0}) tuple(%add.4, %copy.3)
+  %while.7 = (f32[4]{0}, f32[4]{0}) while(%tuple.6), condition=%c, body=%b, metadata={op_name="jit(step)/jvp(model/layers)/while"}
+"""
+    scopes = trace_lib.hlo_scopes(text)
+    assert scopes["%reduce-window.5"] == "jit(step)/jvp(model/embed)/add"
+    assert scopes["%copy.3"] == "jit(step)/jvp(model/layers)/while"
+    assert scopes["%tuple.6"] == "jit(step)/jvp(model/layers)/while"
 
 
 def test_exposed_hidden_split():
-    """Interval arithmetic: a collective fully covered by compute
-    slices is hidden; an uncovered one is exposed."""
+    """Interval arithmetic on device ops: a stage op fully covered by an
+    op outside the stage is hidden; an uncovered one is exposed; an
+    enclosing op (a ``while``) hides nothing."""
     name = "exchange/s00/allreduce/bucket=dense0"
     other = "exchange/s01/allreduce/bucket=dense1"
-    # stage s00's collective spans [0, 3ms]; stage s01's pack (a
-    # compute slice on another row) spans [0, 4ms] and covers it fully
-    events = [
-        {"stage": name, "phase": "collective", "worker": 0, "t": 0.003},
-        {"stage": other, "phase": "pack", "worker": 0, "t": 0.004},
-    ]
-    trace = trace_lib.chrome_trace(events, [name, other],
-                                   [{"t_start": 0.0, "t_end": 0.005}])
-    s = report_lib.summarize_trace(trace)["stages"][name]
-    assert s["hidden_us"] == pytest.approx(s["collective_us"])
+    us = 1000
+    steps = [Span(hooks.STEP, 0, 10 * us)]
+    covered = [Op(0, "%all-reduce.1", 1 * us, 4 * us, f"jit(step)/{name}"),
+               Op(0, "%fusion.2", 0, 2 * us, f"jit(step)/{other}/pack"),
+               Op(0, "%fusion.5", 2 * us, 5 * us, "jit(step)/optim/update")]
+    s = report_lib.stage_timings(Profile(covered, steps, {}),
+                                 [name, other])[name]
+    assert s["hidden_us"] == pytest.approx(s["collective_us"]) == \
+        pytest.approx(3.0)
     assert s["exposed_us"] == pytest.approx(0.0)
 
-    events2 = [{"stage": name, "phase": "collective", "worker": 0,
-                "t": 0.003}]
-    trace2 = trace_lib.chrome_trace(events2, [name],
-                                    [{"t_start": 0.0, "t_end": 0.005}])
-    s2 = report_lib.summarize_trace(trace2)["stages"][name]
-    assert s2["exposed_us"] == pytest.approx(s2["collective_us"])
+    alone = [Op(0, "%while.3", 0, 9 * us, "jit(step)/while"),
+             Op(0, "%all-reduce.1", 1 * us, 3 * us, f"jit(step)/{name}"),
+             Op(0, "%fusion.4", 5 * us, 6 * us,
+                "jit(step)/transpose(jvp(model/head))/dot")]
+    prof = Profile(alone, steps, {})
+    s2 = report_lib.stage_timings(prof, [name])[name]
+    assert s2["exposed_us"] == pytest.approx(s2["collective_us"]) == \
+        pytest.approx(2.0)
+    split = report_lib.layer_split(prof)
+    assert split[hooks.HEAD] == pytest.approx(1e-3)
+    assert split["exchange"] == pytest.approx(2e-3)
+    assert split["other"] == pytest.approx(0.0)
+    assert split["loop"] == pytest.approx(6e-3)      # the while's own
+    assert split["idle"] == pytest.approx(0.0)
 
 
 # ---------------------------------------------------------------------------
