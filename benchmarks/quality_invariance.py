@@ -51,7 +51,9 @@ def _train(cfg, model, params, sad: bool, batch: int, steps=STEPS,
         ex_state = opt.init_exchange_state(g)
     tr = Trainer(model, step, pipe, TrainerConfig(total_steps=steps,
                                                   log_every=steps))
-    res = tr.run(params, opt.init(params), log=lambda s: None,
+    # ``run`` consumes the state it is given; callers reuse ``params``
+    start = jax.tree_util.tree_map(jnp.copy, params)
+    res = tr.run(start, opt.init(start), log=lambda s: None,
                  exchange_state=ex_state)
     return res["history"][-1]["loss"], res["params"]
 

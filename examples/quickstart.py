@@ -55,7 +55,9 @@ def main():
         tr = Trainer(model, step, pipe,
                      TrainerConfig(total_steps=30, log_every=10))
         print(f"training with {name} accumulation:")
-        res = tr.run(params, opt.init(params),
+        # ``run`` consumes the state it is given: train from a copy
+        start = jax.tree_util.tree_map(jnp.copy, params)
+        res = tr.run(start, opt.init(start),
                      log=lambda s: print("   ", s))
         results[name] = res["params"]
     diff = max(float(jnp.max(jnp.abs(a - b))) for a, b in zip(

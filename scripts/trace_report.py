@@ -19,6 +19,9 @@ It renders:
   ``--audit-exchange`` contract);
 * per step: device ms by layer scope (``model/...``, ``optim/update``,
   ``exchange``), the rest, and idle time; idle share by Trainer span;
+* the donated share: the bytes of the train state whose buffers the
+  compiled step reuses for its outputs, over the state's bytes
+  (``donation.json``; 100% when every leaf is donated and aliased);
 * a machine-readable ``--json`` form for CI (the telemetry smoke
   asserts one row per schedule stage and ``wire_exact``).
 
@@ -70,6 +73,11 @@ def main(argv=None) -> int:
         f"{k}={v:.3f}" for k, v in summary["layers_ms"].items()))
     print("device idle, % of the window, by host span: " + "  ".join(
         f"{k}={v:.3f}" for k, v in summary["idle_share_by_span"].items()))
+    don = summary["donation"]
+    if don is not None and don["share"] is not None:
+        print(f"donated: {don['share'] * 100:.2f}% of the train state "
+              f"({don['aliased_bytes']} of {don['state_bytes']} bytes "
+              f"per device reused by the step's outputs)")
     print()
     print(report_lib.render_table(rows))
     exposed = sum(r["exposed_us"] for r in rows)

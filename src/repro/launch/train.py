@@ -411,8 +411,9 @@ def train(argv=None) -> dict:
         recorder.close()
         print(f"metrics written: {args.metrics_jsonl}")
     if args.trace_dir:
-        write_exchange_meta(args, opt, model, params, pipe, g, step,
-                            result, n_dev, sparse_embedding)
+        # ``run`` consumed ``params``: read shapes from its result
+        write_exchange_meta(args, opt, model, result["params"], pipe, g,
+                            step, result, n_dev, sparse_embedding)
     return dict(result, config=cfg, n_workers=workers)
 
 

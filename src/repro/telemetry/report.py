@@ -5,7 +5,8 @@ Operates on a ``telemetry.trace.Profile`` (``load_profile`` of the
 directory ``train.py --trace-dir`` writes): device ops per chip, each
 with its scope path, the Trainer's host spans, and the plan's
 ``exchange.json`` (stage names, wire accounting, the tuner's per-stage
-prediction, runtime-measured wire bytes).  The CLI is
+prediction, runtime-measured wire bytes), and ``donation.json`` (the
+share of the train state the compiled step reuses).  The CLI is
 ``scripts/trace_report.py``.
 
 Definitions (per chip, per step, then averaged over chips):
@@ -29,7 +30,7 @@ import json
 from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 from repro.telemetry import hooks
-from repro.telemetry.trace import Profile, load_profile
+from repro.telemetry.trace import Profile, load_profile, read_donation
 
 Intervals = List[Tuple[int, int]]
 LAYERS = hooks.LAYER_SCOPES + (hooks.EXCHANGE,)
@@ -241,6 +242,7 @@ def summarize_profile(trace_dir: str) -> Dict[str, Any]:
         "wire_exact": wire_exact(rows),
         "layers_ms": layer_split(prof) if devs else {},
         "idle_share_by_span": idle_by_span(prof) if devs else {},
+        "donation": read_donation(trace_dir),
         "rows": rows,
     }
 

@@ -16,17 +16,20 @@ Two tools, neither of which changes the compiled program:
 * ``load_profile(dir)`` — read what ``Trainer.run`` wrote under
   ``TrainerConfig(profile_dir=...)``: the ``jax.profiler`` capture of
   the loop's last steps (``.xplane.pb``) and the compiled step's HLO
-  text.  Device ops come out per chip, each with the scope path that
-  ``jax.named_scope`` wrote into its HLO ``op_name`` metadata (the
-  ``telemetry.hooks`` vocabulary and the ``exchange/sNN/...`` stage
-  names); host spans come from the Python main thread, on the device
-  ops' clock.  ``telemetry.report`` reduces them.
+  text, and (``read_donation``) the share of the train state whose
+  buffers the step reuses.  Device ops come out per chip, each with the
+  scope path that ``jax.named_scope`` wrote into its HLO ``op_name``
+  metadata (the ``telemetry.hooks`` vocabulary and the
+  ``exchange/sNN/...`` stage names); host spans come from the Python
+  main thread, on the device ops' clock.  ``telemetry.report`` reduces
+  them.
 """
 from __future__ import annotations
 
 import dataclasses
 import glob
 import json
+import math
 import os
 import re
 from typing import Any, Callable, Dict, List, Optional
@@ -37,6 +40,7 @@ from repro.telemetry import hooks
 
 EXCHANGE_META = "exchange.json"   # beside a profile: the plan's accounting
 STEP_HLO = "step.hlo.txt"         # beside a profile: op -> scope metadata
+DONATION = "donation.json"        # beside a profile: the state the step reuses
 
 
 # ---------------------------------------------------------------------------
@@ -104,12 +108,40 @@ def plan_trace_meta(plan, n_workers, profile: str = "ethernet",
     return meta
 
 
-def write_step_hlo(trace_dir: str, jit_step, args) -> str:
-    """Write the compiled step's HLO text into ``trace_dir``."""
+def donated_share(compiled, state) -> Dict[str, Any]:
+    """Bytes per device of the step's inputs that its outputs alias
+    (the donated buffers it reuses) against the bytes per device of the
+    train state ``state``: ``share`` is 1.0 where every state leaf is
+    reused, and None where the backend reports no memory analysis."""
+    state_bytes = sum(math.prod(x.sharding.shard_shape(x.shape))
+                      * x.dtype.itemsize
+                      for x in jax.tree_util.tree_leaves(state))
+    stats = compiled.memory_analysis()
+    aliased = None if stats is None else int(stats.alias_size_in_bytes)
+    return {"aliased_bytes": aliased, "state_bytes": int(state_bytes),
+            "share": (aliased / state_bytes
+                      if aliased is not None and state_bytes else None)}
+
+
+def write_step_hlo(trace_dir: str, jit_step, args, n_state: int) -> str:
+    """Write the compiled step's HLO text into ``trace_dir``, and beside
+    it (``donation.json``) the ``donated_share`` of the train state, the
+    first ``n_state`` of ``args``."""
+    compiled = jit_step.lower(*args).compile()
     path = os.path.join(trace_dir, STEP_HLO)
     with open(path, "w") as f:
-        f.write(jit_step.lower(*args).compile().as_text())
+        f.write(compiled.as_text())
+    with open(os.path.join(trace_dir, DONATION), "w") as f:
+        json.dump(donated_share(compiled, args[:n_state]), f, indent=1)
     return path
+
+
+def read_donation(trace_dir: str) -> Optional[Dict[str, Any]]:
+    path = os.path.join(trace_dir, DONATION)
+    if not os.path.exists(path):
+        return None
+    with open(path) as f:
+        return json.load(f)
 
 
 def write_meta(meta: Dict[str, Any], trace_dir: str) -> str:

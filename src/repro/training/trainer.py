@@ -43,7 +43,15 @@ class Trainer:
 
     def run(self, params, opt_state, log: Callable[[str], None] = print,
             exchange_state: Any = None) -> Dict[str, Any]:
-        """Run the loop.  ``exchange_state`` (an ``ExchangeState`` from
+        """Run the loop.  The step is jitted with the train state donated:
+        ``run`` owns the ``params``, ``opt_state`` and ``exchange_state``
+        it is given, their buffers are reused for the step's outputs
+        (the passed-in arrays are deleted after the first step), and
+        the caller continues from the state ``run`` returns.  A caller
+        that needs the starting state afterwards passes a copy.  The
+        batch is never donated.
+
+        ``exchange_state`` (an ``ExchangeState`` from
         ``opt.init_exchange_state``) switches the step to the stateful
         calling convention — the codec residuals then ride the train
         state: threaded through every jit_step, saved in every
@@ -72,7 +80,10 @@ class Trainer:
                         cfg.checkpoint_dir, (params, opt_state), step=s)
                 log(f"resumed from step {start_step}")
 
-        jit_step = jax.jit(self.step_fn)
+        # one copy of the train state on the device: each step's outputs
+        # take the buffers of its inputs
+        jit_step = jax.jit(self.step_fn,
+                           donate_argnums=(0, 1, 2) if stateful else (0, 1))
         history: List[Dict[str, float]] = []
         tokens_seen = 0
         overflow_pending: List[Any] = []  # un-synced device bools
@@ -174,7 +185,7 @@ class Trainer:
         if profile_from is not None and batch is not None:
             args = ((params, opt_state, exchange_state, batch) if stateful
                     else (params, opt_state, batch))
-            write_step_hlo(cfg.profile_dir, jit_step, args)
+            write_step_hlo(cfg.profile_dir, jit_step, args, len(args) - 1)
         if rec is not None:
             rec.flush()
         return {"params": params, "opt_state": opt_state,

@@ -274,13 +274,19 @@ def test_trainer_checkpoints_and_resumes_exchange_state(tmp_path):
             checkpoint_every=2, checkpoint_dir=str(tmp_path),
             resume=resume))
 
+    def copy(tree):
+        # ``run`` consumes the state it is given: each fresh run gets a copy
+        return jax.tree_util.tree_map(jnp.copy, tree)
+
     straight = trainer(4, resume=False).run(
-        params, opt.init(params), log=lambda s: None, exchange_state=ex0)
+        copy(params), opt.init(params), log=lambda s: None,
+        exchange_state=copy(ex0))
 
     for f in os.listdir(tmp_path):
         os.remove(os.path.join(tmp_path, f))
-    trainer(2, resume=False).run(params, opt.init(params),
-                                 log=lambda s: None, exchange_state=ex0)
+    trainer(2, resume=False).run(copy(params), opt.init(params),
+                                 log=lambda s: None,
+                                 exchange_state=copy(ex0))
     resumed = trainer(4, resume=True).run(
         params, opt.init(params), log=lambda s: None, exchange_state=ex0)
 

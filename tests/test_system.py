@@ -103,7 +103,9 @@ def test_full_stack_train_checkpoint_resume_serve():
         tr = Trainer(model, step, pipe, TrainerConfig(
             total_steps=4, log_every=2, checkpoint_every=2,
             checkpoint_dir=d))
-        res = tr.run(params, opt.init(params), log=lambda s: None)
+        # ``run`` consumes the state it is given: train from a copy
+        res = tr.run(jax.tree_util.tree_map(jnp.copy, params),
+                     opt.init(params), log=lambda s: None)
         # resume continues from step 4
         tr2 = Trainer(model, step, pipe, TrainerConfig(
             total_steps=6, log_every=2, checkpoint_every=2,

@@ -367,7 +367,9 @@ def _run_trainer(profile_dir, ckpt_dir, steps=3, log_every=1, profile_steps=2):
         checkpoint_dir=None if ckpt_dir is None else str(ckpt_dir),
         profile_dir=None if profile_dir is None else str(profile_dir),
         profile_steps=profile_steps))
-    res = tr.run(params, opt.init(params), log=lambda s: None)
+    # ``run`` consumes the state it is given; the caller keeps ``params``
+    res = tr.run(jax.tree_util.tree_map(jnp.copy, params), opt.init(params),
+                 log=lambda s: None)
     return res, opt, step, params
 
 
