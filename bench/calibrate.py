@@ -5,16 +5,17 @@
         --seeds 101-112 --control-seeds 101-103 --out chiprun_out/cal.json
 
 In one process, with the step compiled once: for every seed, the
-program's first steps against the plain reference (the lower readings);
-for each control seed, the controls and the planted faults against the
-reference (the upper readings).  Two controls: the reference in float8
-put in the program's place, and the program's own lower-precision path,
-its int8 gradient codec, switched on.  The faults are planted in the
-reference: half of every batch left out; the embedding's gradient
-densified by overwriting repeated rows instead of adding them; and on
-several chips the exchange left out (each chip trains on its own rows).
-A step that returns its state unchanged reads 1 on ``delta_gap`` by
-definition and needs no run.
+program's first steps against the config's plain reference (the lower
+readings); for each control seed, the controls and the planted faults
+against the reference (the upper readings).  Two controls: the
+reference in float8 put in the program's place, and the program's own
+lower-precision path, its int8 gradient codec, switched on.  The faults
+are those the reference module plants (its ``FAULTS``; those in
+``MULTI_CHIP_FAULTS`` only on several chips): for the decoder, half of
+every batch left out; the embedding's gradient densified by overwriting
+repeated rows instead of adding them; and on several chips the exchange
+left out (each chip trains on its own rows).  A step that returns its
+state unchanged reads 1 on ``delta_gap`` by definition and needs no run.
 """
 from __future__ import annotations
 
@@ -61,8 +62,9 @@ def main(argv=None) -> int:
                                                      codec="int8")),
                          devices, feed)
     quiet = lambda msg: None                                  # noqa: E731
-    faults = ("half_batch", "dup_overwrite") + (
-        ("no_exchange",) if chips > 1 else ())
+    ref_mod = harness.reference(cfg)
+    faults = tuple(f for f in ref_mod.FAULTS if f != "none" and (
+        chips > 1 or f not in ref_mod.MULTI_CHIP_FAULTS))
     kinds = ("program", "control_fp8", "control_int8") + faults
     out = {"workload": args.workload, "warmup": warmup, "device":
            f"{devices[0].platform} {devices[0].device_kind} x{len(devices)}",
@@ -70,7 +72,7 @@ def main(argv=None) -> int:
 
     def ref_of(seed, pool, precision="f32", fault="none"):
         return harness.reference_readings(cfg, seed, pool, warmup, chips,
-                                          devices[0], precision, fault)
+                                          devices, precision, fault)
 
     for seed in seed_list(args.seeds):
         t0 = time.perf_counter()

@@ -10,8 +10,9 @@ sizes for every seed:
   (the skewed id distribution that makes the embedding gradient sparse);
 * ``labels``: the next token of the same stream;
 * ``loss_mask``: ones (every position is a target);
-* ``frontend``: the encoder-state stub, float32 normal, ``frames`` x
-  ``d_model`` per row.
+* ``frontend``: where the config has ``frontend_frames``, the
+  encoder-state stub, float32 normal, ``frames`` x ``d_model`` per row
+  (drawn after each batch's ids, from the same stream).
 
 ``PoolFeed`` hands them to the program's ``Trainer`` through its
 ``batch_at(step)`` interface, cycling the pool from an offset that the
@@ -36,7 +37,8 @@ def make_pool(traffic: Dict, cfg: Dict, seed: int, chips: int
               ) -> List[Dict[str, np.ndarray]]:
     rows = traffic["batch_per_chip"] * chips
     seq = traffic["seq_len"]
-    frames, d, vocab = cfg["frontend_frames"], cfg["d_model"], cfg["vocab"]
+    frames, d = cfg.get("frontend_frames"), cfg["d_model"]
+    vocab = cfg["vocab"]
     dist = traffic["tokens"]
     if dist["kind"] != "zipf":
         raise ValueError(f"unknown token distribution {dist['kind']!r}")
@@ -45,13 +47,12 @@ def make_pool(traffic: Dict, cfg: Dict, seed: int, chips: int
     for _ in range(traffic["pool_batches"]):
         raw = rng.zipf(dist["a"], size=(rows, seq + 1))
         toks = np.minimum(raw - 1, vocab - 1).astype(np.int32)
-        pool.append({
-            "tokens": toks[:, :-1],
-            "labels": toks[:, 1:],
-            "loss_mask": np.ones((rows, seq), np.float32),
-            "frontend": rng.standard_normal((rows, frames, d),
-                                            dtype=np.float32),
-        })
+        batch = {"tokens": toks[:, :-1], "labels": toks[:, 1:],
+                 "loss_mask": np.ones((rows, seq), np.float32)}
+        if frames is not None:
+            batch["frontend"] = rng.standard_normal((rows, frames, d),
+                                                    dtype=np.float32)
+        pool.append(batch)
     return pool
 
 

@@ -1,5 +1,10 @@
 """The phases of a run that ``run.py`` and ``calibrate.py`` share.
 
+Everything model-specific comes from the config's reference module,
+``bench/references/<cfg["reference"]>.py`` (``reference(cfg)``): the
+weights, the reference's readings and faults, the optimizer's first
+moment decay and the counts.
+
 ``first_steps`` builds nothing: it takes the built program, gives it the
 benchmark's weights from the seed, drives the first steps through the
 window's own call (``Trainer.run``) and feed on distinct rows, and reads
@@ -12,6 +17,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import importlib
 import time
 from typing import Dict, List, Optional, Tuple
 
@@ -20,7 +26,6 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from bench import program
-from bench.references import xattn_decoder as reference
 
 CHECK_STEPS = 3              # steps the reference follows
 
@@ -65,9 +70,17 @@ class CompileCounter:
             self.on = False
 
 
+def reference(cfg: Dict):
+    """The plain reference module that the config file names, after it
+    has checked that it models the config."""
+    mod = importlib.import_module(f"bench.references.{cfg['reference']}")
+    mod.check_config(cfg)
+    return mod
+
+
 def weights(prog, cfg: Dict, seed: int):
-    return reference.init_params(cfg, seed, cfg["dtype"],
-                                 NamedSharding(prog.mesh, P()))
+    return reference(cfg).init_params(cfg, seed, cfg["dtype"],
+                                      NamedSharding(prog.mesh, P()))
 
 
 def first_steps(prog, cfg: Dict, seed: int, feed, log) -> Tuple[Dict, Dict]:
@@ -77,6 +90,7 @@ def first_steps(prog, cfg: Dict, seed: int, feed, log) -> Tuple[Dict, Dict]:
     AdamW's first moment (per chip as norms, the first chip's whole on
     the host), the change of the parameters against the seed's weights
     made afresh."""
+    b1 = reference(cfg).ADAM_B1
     params = weights(prog, cfg, seed)
     program.check_layout(prog, params)
     params, opt_state = program.init_state(prog, params)
@@ -84,8 +98,8 @@ def first_steps(prog, cfg: Dict, seed: int, feed, log) -> Tuple[Dict, Dict]:
     r1 = program.run(prog, params, opt_state, 1, 1, log)
     del params, opt_state
     moment = program.first_moment(r1["opt_state"])
-    grad_norm = program.per_device_norms(prog, moment) / (1 - reference.ADAM_B1)
-    grad = [np.asarray(x.addressable_shards[0].data) / (1 - reference.ADAM_B1)
+    grad_norm = program.per_device_norms(prog, moment) / (1 - b1)
+    grad = [np.asarray(x.addressable_shards[0].data) / (1 - b1)
             for x in jax.tree_util.tree_leaves(moment)]
     del moment
     feed.offset = 1
@@ -123,10 +137,13 @@ def peak_bytes(devices) -> int:
 
 
 def reference_readings(cfg: Dict, seed: int, pool: List, warmup: int,
-                       chips: int, device, precision: str = "f32",
+                       chips: int, devices, precision: str = "f32",
                        fault: str = "none") -> Dict:
-    params0 = reference.init_params(
-        cfg, seed, cfg["dtype"], jax.sharding.SingleDeviceSharding(device))
-    with jax.default_device(device):
-        return reference.train_readings(params0, pool[:CHECK_STEPS], cfg,
-                                        warmup, chips, precision, fault)
+    """The reference's readings of the first steps, its blocks of rows
+    dealt out over ``devices``."""
+    ref = reference(cfg)
+    params0 = ref.init_params(
+        cfg, seed, cfg["dtype"], jax.sharding.SingleDeviceSharding(devices[0]))
+    with jax.default_device(devices[0]):
+        return ref.train_readings(params0, pool[:CHECK_STEPS], cfg,
+                                  warmup, chips, devices, precision, fault)

@@ -1,10 +1,10 @@
-"""Least time of densifying the tied embedding's gradient (bytes from
+"""Least time of densifying the embedding's gradient (bytes from
 bench/flops.py over the chip's HBM bandwidth) over the measured device
 time of the ops that do it, per step, averaged over the chips.
 
 The ops are those whose scope ends in the exchange plan's ``pack`` with
-a scatter (XLA's, which adds the rows into the head's dense gradient in
-place) or the densify kernel where the plan uses it."""
+a scatter (XLA's, which adds the rows into the dense gradient, in place
+in the tied head's) or the densify kernel where the plan uses it."""
 from bench import trace as T
 
 

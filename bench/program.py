@@ -33,24 +33,56 @@ LAUNCHER_DEFAULTS = dict(
     codec="identity", backend="jax", error_feedback=False, overlap=None,
     zero1=False, param_codec="identity", warmup=400)
 
-# config-file keys that are fields of the program's ArchConfig
-_ARCH_KEYS = ("family", "n_layers", "d_model", "n_heads", "n_kv_heads",
-              "d_ff", "vocab", "tied_embeddings", "rope_theta", "norm_eps",
-              "dtype")
+# config-file keys that name the file's own configuration, not a field
+# of the program's ArchConfig that happens to share the name
+_FILE_KEYS = ("name", "source")
 
 
 def arch_config(cfg: Dict):
     """The program's ArchConfig for a config file: the registered arch
-    with the file's sizes, checked field by field."""
+    (``cfg["arch"]``) with every key of the file that names one of its
+    fields set on it (a nested block such as ``moe``, ``mla`` or
+    ``frontend``, given as an object, replaces those keys of the
+    registered block), and ``frontend_frames``, where the file has it,
+    as the cross-attention frontend's frames; then checked field by
+    field against the file."""
     base = get_config(cfg["arch"])
-    over = {k: cfg[k] for k in _ARCH_KEYS}
-    arch = base.with_(**over, frontend=dataclasses.replace(
-        base.frontend, n_embeds=cfg["frontend_frames"]))
-    got = {k: getattr(arch, k) for k in _ARCH_KEYS}
-    got["frontend_frames"] = arch.frontend.n_embeds
-    want = {k: cfg[k] for k in got}
-    if got != want or not arch.frontend.cross_attention \
-            or arch.resolved_head_dim * arch.n_heads != arch.d_model:
+    fields = {f.name for f in dataclasses.fields(base)}
+    over = {}
+    for k, v in cfg.items():
+        if k not in fields or k in _FILE_KEYS:
+            continue
+        if isinstance(v, dict):
+            block = getattr(base, k)
+            if block is None:
+                raise ValueError(f"{cfg['arch']} has no {k!r} block to set")
+            try:
+                v = dataclasses.replace(block, **v)
+            except TypeError as e:
+                raise ValueError(f"{k}: {e}") from None
+        over[k] = v
+    if "frontend_frames" in cfg:
+        frontend = over.get("frontend", base.frontend)
+        if frontend is None:
+            raise ValueError(f"{cfg['arch']} has no frontend for "
+                             f"frontend_frames")
+        over["frontend"] = dataclasses.replace(
+            frontend, n_embeds=cfg["frontend_frames"])
+    arch = base.with_(**over)
+
+    def as_file(x, like):
+        if isinstance(like, dict):
+            return {k: getattr(x, k, None) for k in like}
+        return x
+
+    want = {k: cfg[k] for k in over if k in cfg}
+    got = {k: as_file(getattr(arch, k), want[k]) for k in want}
+    if "frontend_frames" in cfg:
+        want["frontend_frames"] = cfg["frontend_frames"]
+        got["frontend_frames"] = arch.frontend.n_embeds
+        want["cross_attention"], got["cross_attention"] = \
+            True, arch.frontend.cross_attention
+    if got != want:
         raise ValueError(f"program config {got} differs from {want}")
     return arch
 

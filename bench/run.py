@@ -7,7 +7,9 @@
 Everything about the cell is data: ``BENCHMARK.json`` names its config
 file (``bench/configs/``), its traffic file (``bench/traffic/``) and its
 metrics (one reader each under ``bench/metrics/``); the comparison's
-limits are in ``bench/limits/<workload>.json``.
+limits are in ``bench/limits/<workload>.json``; the config file names
+its plain reference (``"reference"``: a module of ``bench/references/``),
+which gives the weights, the readings compared and the counts.
 
 A run: batches and weights from ``--seed``; the program's training step
 built as the launcher builds it; the first steps through the program's
@@ -16,8 +18,8 @@ sizes the window; then the window: whole steps of one ``Trainer.run``
 call for about ``--seconds``, no compilation inside (counted; a run with
 any fails).  ``--trace 1`` adds a traced window of a few steps and
 reports the per-layer metrics instead of the end-to-end ones.  After the
-windows the program's state is freed and the plain reference repeats the
-first steps; the comparison decides ``correct``.
+windows the program's state is freed and the config's plain reference
+repeats the first steps; the comparison decides ``correct``.
 
 The last line of standard output is one JSON object (``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device``, with ``--trace 1``
@@ -141,6 +143,7 @@ def main(argv=None, allow_cpu: bool = False, spec=None,
     from bench import check, flops, generator, harness, program
     from bench.trace import breakdown, device_seconds, load as load_trace
 
+    reference = harness.reference(cfg)
     tr = generator.load(cell["traffic"], data_dir)
     if tr["chips"] != chips:
         raise SystemExit(f"traffic {cell['traffic']} is for {tr['chips']} "
@@ -177,7 +180,7 @@ def main(argv=None, allow_cpu: bool = False, spec=None,
         setup_s=setup_s, window_s=window_s, window_steps=steps,
         window_tokens=steps * rows * seq, input_ms=hist["data_ms"],
         peak_bytes=0, chips=chips,
-        flops_per_step=flops.train_step_flops(cfg, rows, seq),
+        flops_per_step=reference.train_step_flops(cfg, rows, seq),
         densify_bytes=flops.densify_bytes(
             cfg, per_chip, flops.unique_rows(
                 [b["tokens"] for b in pool], chips),
@@ -209,7 +212,7 @@ def main(argv=None, allow_cpu: bool = False, spec=None,
 
     t_ref = time.perf_counter()
     ref = harness.reference_readings(cfg, args.seed, pool, tr["launcher"]
-                                     ["warmup"], chips, devices[0])
+                                     ["warmup"], chips, devices)
     got = check.gaps(readings, ref)
     log(f"bench: reference and comparison {time.perf_counter() - t_ref:.3f} s"
         f", run {time.perf_counter() - T_START:.3f} s; "

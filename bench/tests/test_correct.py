@@ -27,7 +27,8 @@ def run_cell(fault, workload, seed, trace=0):
     return json.loads(p.stdout.strip().splitlines()[-1])
 
 
-@pytest.mark.parametrize("workload,trace", [("tiny-1", 0), ("tiny-4", 1)])
+@pytest.mark.parametrize("workload,trace", [("tiny-1", 0), ("tiny-4", 1),
+                                            ("tiny-dec-1", 0)])
 def test_sound_run_is_correct(workload, trace):
     out = run_cell("none", workload, 2 ** 31 + 17, trace)
     assert out["correct"] is True, out["checks"]
@@ -46,27 +47,43 @@ def test_sound_run_is_correct(workload, trace):
     ("state_unchanged", "tiny-1"), ("half_batch", "tiny-1"),
     ("dup_overwrite", "tiny-1"), ("state_unchanged", "tiny-4"),
     ("half_batch", "tiny-4"), ("no_exchange", "tiny-4"),
-    ("dup_overwrite", "tiny-4")])
+    ("dup_overwrite", "tiny-4"), ("half_batch", "tiny-dec-1"),
+    ("dup_overwrite", "tiny-dec-1")])
 def test_fault_is_not_correct(fault, workload):
     out = run_cell(fault, workload, 23)
     assert out["correct"] is False, out["checks"]
+
+
+def control_and_sound(config, traffic, workload, seed):
+    """The float8 control's verdict and the reference's own, against
+    the cell's limits."""
+    import jax
+    cfg = json.loads((DATA / "configs" / f"{config}.json").read_text())
+    tr = generator.load(traffic, DATA)
+    pool = generator.make_pool(tr, cfg, seed, 1)
+    devs = jax.devices()[:1]
+    warmup = tr["launcher"]["warmup"]
+    ref = harness.reference_readings(cfg, seed, pool, warmup, 1, devs)
+    ctl = harness.reference_readings(cfg, seed, pool, warmup, 1, devs,
+                                     "fp8")
+    limits = check.load_limits(workload, DATA)
+    return (check.judge(check.gaps(ctl, ref), limits)["correct"],
+            check.judge(check.gaps(ref, ref), limits)["correct"])
 
 
 @pytest.mark.parametrize("seed", [31, 32, 33])
 def test_control_is_not_correct(seed):
     """The reference in float8 put in the program's place fails the
     limits that the program passes."""
-    import jax
-    cfg = json.loads((DATA / "configs" / "tiny.json").read_text())
-    tr = generator.load("tiny_dp1", DATA)
-    pool = generator.make_pool(tr, cfg, seed, 1)
-    dev = jax.devices()[0]
-    warmup = tr["launcher"]["warmup"]
-    ref = harness.reference_readings(cfg, seed, pool, warmup, 1, dev)
-    ctl = harness.reference_readings(cfg, seed, pool, warmup, 1, dev, "fp8")
-    limits = check.load_limits("tiny-1", DATA)
-    assert check.judge(check.gaps(ctl, ref), limits)["correct"] is False
-    assert check.judge(check.gaps(ref, ref), limits)["correct"] is True
+    assert control_and_sound("tiny", "tiny_dp1", "tiny-1", seed) == \
+        (False, True)
+
+
+@pytest.mark.parametrize("seed", [31, 32, 33])
+def test_control_is_not_correct_without_frontend(seed):
+    """The same for the decoder without cross-attention, untied."""
+    assert control_and_sound("tiny-dec", "tiny_dp1", "tiny-dec-1",
+                             seed) == (False, True)
 
 
 def test_no_chip_no_result(tmp_path):

@@ -4,7 +4,8 @@ import types
 import pytest
 
 from bench import trace as T
-from bench.metrics import densify_roofline, device_idle_share, exchange_ms
+from bench.metrics import (collective_exposed_ms, densify_roofline,
+                           device_idle_share, exchange_ms)
 
 US = 1000     # ns
 
@@ -73,6 +74,21 @@ def test_exposed_is_none_without_collectives():
     tr = synthetic()
     tr.ops = [o for o in tr.ops if not T.is_collective(o)]
     assert T.exposed(tr, 0, T.window(tr, 0), T.is_collective) is None
+
+
+def test_collective_exposed_ms_is_the_worst_chips_per_step():
+    # device 0: 10 us of its 20 us all-reduce under no other op, device
+    # 1: all 20 us; two steps
+    assert collective_exposed_ms.read(record(synthetic())) == \
+        pytest.approx(0.020)
+
+
+def test_collective_exposed_ms_reads_only_the_exchange():
+    tr = synthetic()
+    tr.ops = [T.Op(o.device, o.name, o.start, o.end, "jit(step)/layers")
+              if T.is_collective(o) else o for o in tr.ops]
+    assert collective_exposed_ms.read(record(tr)) is None
+    assert collective_exposed_ms.read(record(None)) is None
 
 
 def test_densify_roofline():
