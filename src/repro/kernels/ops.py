@@ -12,6 +12,7 @@ one place, ``pallas_interpret``, from the backend: interpreted on the CPU
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -24,6 +25,7 @@ from repro.kernels.flash_attention import flash_attention_pallas, \
     DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K
 from repro.kernels.quantize import quantize_pallas, QMAX
 from repro.kernels.ssd import ssd_pallas
+from repro.telemetry import hooks as scopes
 
 
 def _round_up(x: int, m: int) -> int:
@@ -118,15 +120,22 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True, window: Optional[int] = None,
                     impl: str = "pallas",
                     block_q: int = DEFAULT_BLOCK_Q,
-                    block_k: int = DEFAULT_BLOCK_K) -> jax.Array:
+                    block_k: Optional[int] = None) -> jax.Array:
     """Multi-head attention, shapes q (B,Sq,H,D), k/v (B,Sk,Hkv,D) (GQA ok).
 
     impl:
-      pallas       Pallas kernel; needs equal q/v head dims
+      pallas       Pallas kernel; needs equal q/v head dims; ``block_k``
+                   defaults to ``DEFAULT_BLOCK_K``
       xla          full-softmax reference (small shapes only)
-      xla_chunked  pure-JAX online-softmax scan over kv blocks — the
-                   memory-safe path the 512-device dry-run lowers; takes
-                   unequal q/v head dims (MLA)
+      xla_chunked  pure JAX, takes unequal q/v head dims (MLA).  Where the
+                   whole kv fits one block (``Sk <= block_k``, default
+                   ``XLA_BLOCK_K``) it is one exact softmax with a
+                   recomputing backward that saves q, k, v, the output
+                   and the f32 log-sum-exp; longer kv runs the
+                   online-softmax scan over ``block_k`` chunks.  Both
+                   hand the MXU q, k and v in their own dtype and keep
+                   the softmax and every accumulation in f32
+                   (``_chunked_attention``).
     """
     h = q.shape[2]
     k = _expand_kv(k, h)
@@ -138,22 +147,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if impl == "xla":
         return ref.attention_ref(q, k, v, causal=causal, window=window)
     if impl == "xla_chunked":
-        # DEFAULT_BLOCK_K (128) is the MXU tile for the Pallas kernel; the
-        # XLA scan wants much larger kv chunks — each scan step spills the
-        # (B,H,S,D) accumulator to HBM, so traffic ~ S/block_k spills
-        # (measured 1.7x prefill memory-term win at 4096 —
-        # EXPERIMENTS.md §Perf H5).  Explicit block_k is honoured.
-        bk = 4096 if block_k == DEFAULT_BLOCK_K else block_k
-        # never pad beyond the real kv length: short sequences would
-        # otherwise execute (and the roofline would bill) up to
-        # block_k/sk times the useful attention flops
-        bk = min(bk, _round_up(k.shape[1], 8))
         return _chunked_attention(q, k, v, causal=causal, window=window,
-                                  block_k=bk)
+                                  block_k=block_k or XLA_BLOCK_K)
     b, sq, _, d = q.shape
     sk = k.shape[1]
     bq = min(block_q, _round_up(sq, 8))
-    bk = min(block_k, _round_up(sk, 8))
+    bk = min(block_k or DEFAULT_BLOCK_K, _round_up(sk, 8))
     sqp, skp = _round_up(sq, bq), _round_up(sk, bk)
     scale = d ** -0.5
 
@@ -173,14 +172,39 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return out[:, :sq]
 
 
-def _chunked_attention(q, k, v, causal: bool, window: Optional[int],
-                       block_k: int = 4096) -> jax.Array:
-    """Online-softmax scan over kv chunks in pure JAX (lax.scan).
+NEG_INF = -1e30
+# The XLA path's kv block.  DEFAULT_BLOCK_K (128) is the Pallas kernel's
+# MXU tile; the XLA scan wants far larger chunks, since each scan step
+# spills the (B,H,Sq,D) accumulator to HBM (measured 1.7x prefill
+# memory-term win at 4096 -- EXPERIMENTS.md section Perf H5).
+XLA_BLOCK_K = 4096
 
-    Mathematically identical to the Pallas kernel; O(Sq * block_k) live
-    memory.  This is what the production dry-run lowers (Pallas-TPU cannot
-    compile on the CPU-only container).
+
+def _chunked_attention(q, k, v, causal: bool, window: Optional[int],
+                       block_k: int = XLA_BLOCK_K) -> jax.Array:
+    """Attention in pure JAX, mathematically identical to the Pallas
+    kernel.  This is what the training step and the production dry-run
+    lower (Pallas-TPU cannot compile on the CPU-only container).
+
+    ``Sk <= block_k``: one block (``_one_block_attention``): the exact
+    softmax in f32 over all of kv, under a ``custom_vjp`` that saves only
+    q, k, v, the output and the f32 log-sum-exp, and recomputes the
+    scores in the backward, so no score-sized f32 residual reaches HBM
+    or the layer scan's stores.  ``Sk > block_k``: an online-softmax
+    ``lax.scan`` over kv chunks, O(Sq * block_k) live memory.  Both feed
+    the MXU q, k and v in their own dtype (bf16 in training) with f32
+    accumulation.
     """
+    if k.shape[1] <= block_k:
+        with jax.named_scope(scopes.ONE_BLOCK):
+            return _one_block_attention(q, k, v, causal, window)
+    with jax.named_scope(scopes.KV_SCAN):
+        return _kv_scan_attention(q, k, v, causal, window, block_k)
+
+
+def _kv_scan_attention(q, k, v, causal: bool, window: Optional[int],
+                       block_k: int) -> jax.Array:
+    """Online-softmax scan over kv chunks of ``block_k`` (lax.scan)."""
     b, sq, h, d = q.shape
     dv = v.shape[-1]                      # may differ from d (MLA)
     sk = k.shape[1]
@@ -223,7 +247,72 @@ def _chunked_attention(q, k, v, causal: bool, window: Optional[int],
     return out.transpose(0, 2, 1, 3).astype(q.dtype)
 
 
-NEG_INF = -1e30
+def _scores(q, k, causal: bool, window: Optional[int]) -> jax.Array:
+    """Scaled f32 scores (B, H, Sq, Sk) of one block, -inf where masked.
+    The MXU takes q and k in their own dtype; a power-of-two scale (head
+    dim 64) is applied to q first, where it rounds nothing."""
+    sq, sk, d = q.shape[1], k.shape[1], q.shape[-1]
+    scale = d ** -0.5
+    exact = math.frexp(scale)[0] == 0.5
+    if exact:
+        q = q * jnp.asarray(scale, q.dtype)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                   preferred_element_type=jnp.float32)
+    if not exact:
+        s = s * scale
+    if causal or window is not None:
+        q_pos = jnp.arange(sq)[:, None] + (sk - sq)
+        k_pos = jnp.arange(sk)[None, :]
+        mask = jnp.ones((sq, sk), bool)
+        if causal:
+            mask &= q_pos >= k_pos
+        if window is not None:
+            mask &= (q_pos - k_pos) < window
+        s = jnp.where(mask, s, -jnp.inf)
+    return s
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _one_block_attention(q, k, v, causal: bool, window: Optional[int]):
+    """Exact attention over one kv block; see ``_chunked_attention``."""
+    return _one_block_fwd(q, k, v, causal, window)[0]
+
+
+def _one_block_fwd(q, k, v, causal, window):
+    s = _scores(q, k, causal, window)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    # a row with no visible key has m = -inf: its p is all 0, so its
+    # output is 0 and its lse +inf (the backward's p is 0 there too)
+    m = jnp.where(m == -jnp.inf, 0.0, m)
+    p = jnp.exp(s - m)
+    l = jnp.sum(p, axis=-1)
+    o = jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
+                   preferred_element_type=jnp.float32)
+    o = (o / jnp.maximum(l, 1e-30).transpose(0, 2, 1)[..., None]
+         ).astype(q.dtype)
+    lse = jnp.where(l > 0, m[..., 0] + jnp.log(l), jnp.inf)
+    return o, (q, k, v, o, lse)
+
+
+def _one_block_bwd(causal, window, res, do):
+    q, k, v, o, lse = res
+    scale = q.shape[-1] ** -0.5
+    p = jnp.exp(_scores(q, k, causal, window) - lse[..., None])
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                    axis=-1).transpose(0, 2, 1)          # (B, H, Sq)
+    dp = jnp.einsum("bqhd,bkhd->bhqk", do, v,
+                    preferred_element_type=jnp.float32)
+    ds = (p * (dp - delta[..., None])).astype(q.dtype)
+    dq = jnp.einsum("bhqk,bkhd->bqhd", ds, k,
+                    preferred_element_type=jnp.float32) * scale
+    dk = jnp.einsum("bhqk,bqhd->bkhd", ds, q,
+                    preferred_element_type=jnp.float32) * scale
+    dv = jnp.einsum("bhqk,bqhd->bkhd", p.astype(do.dtype), do,
+                    preferred_element_type=jnp.float32)
+    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+
+
+_one_block_attention.defvjp(_one_block_fwd, _one_block_bwd)
 
 
 # ---------------------------------------------------------------------------

@@ -34,6 +34,7 @@ __all__ = [
     "clear_wire_recorder", "stage_scope", "current_stage",
     "record_collective", "UNATTRIBUTED", "EMBED", "LAYERS", "SELF_ATTN",
     "CROSS_ATTN", "FFN", "HEAD", "OPTIM", "EXCHANGE", "LAYER_SCOPES",
+    "ONE_BLOCK", "KV_SCAN",
     "STEP", "FETCH", "DEVICE_PUT", "DISPATCH", "LOG", "CHECKPOINT",
     "TRAINER_SPANS", "in_scope",
 ]
@@ -52,6 +53,10 @@ HEAD = "model/head"              # Model.loss: tied logits + cross-entropy
 OPTIM = "optim/update"           # train_step: optimizer update + apply
 EXCHANGE = "exchange"            # core/exchange.py: exchange/sNN/<kind>/...
 LAYER_SCOPES = (EMBED, LAYERS, SELF_ATTN, CROSS_ATTN, FFN, HEAD, OPTIM)
+# nested in SELF_ATTN / CROSS_ATTN: which path ``kernels.ops``'s XLA
+# attention took, so a profile splits ``attention_ms`` between them
+ONE_BLOCK = "attention/one_block"  # whole kv in one block, custom_vjp
+KV_SCAN = "attention/kv_scan"      # online-softmax scan over kv chunks
 
 # -- host spans (``jax.profiler``) of ``Trainer.run``, in step order --------
 STEP = "train"                   # StepTraceAnnotation around one step

@@ -1,4 +1,6 @@
 """Per-kernel shape/dtype sweeps: Pallas (interpret mode) vs ref oracle."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -6,6 +8,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels import ops, ref
+from repro.telemetry import hooks
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -101,6 +104,97 @@ def test_flash_chunked_matches_ref(b, sq, sk, h, hkv, d, window, causal):
     chk = ops.flash_attention(q, k, v, causal=causal, window=window,
                               impl="xla_chunked", block_k=8)
     np.testing.assert_allclose(chk, exp, rtol=3e-5, atol=3e-5)
+
+
+ONE_BLOCK_CASES = [
+    # b, sq, sk, h, hkv, d, dv, window, causal
+    (2, 16, 16, 4, 2, 64, 64, None, True),      # causal self, GQA
+    (2, 24, 40, 2, 2, 32, 32, None, False),     # bidirectional cross
+    (1, 32, 32, 4, 1, 16, 16, 8, True),         # MQA + window
+    (1, 17, 23, 3, 3, 48, 48, None, True),      # ragged, non-multiple shapes
+    (1, 16, 16, 2, 2, 48, 32, None, True),      # MLA: dv != d
+    (1, 12, 5, 2, 2, 16, 16, None, True),       # 7 rows see no key
+]
+
+
+def _qkv(key, b, sq, sk, h, hkv, d, dv, dtype):
+    ks = jax.random.split(key, 4)
+    return (jax.random.normal(ks[0], (b, sq, h, d)).astype(dtype),
+            jax.random.normal(ks[1], (b, sk, hkv, d)).astype(dtype),
+            jax.random.normal(ks[2], (b, sk, hkv, dv)).astype(dtype),
+            jax.random.normal(ks[3], (b, sq, h, dv)))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("b,sq,sk,h,hkv,d,dv,window,causal",
+                         ONE_BLOCK_CASES)
+def test_flash_one_block_matches_ref(b, sq, sk, h, hkv, d, dv, window,
+                                     causal, dtype):
+    """``xla_chunked`` at its default block_k holds these kv lengths in
+    one block: its output and its recomputing backward's gradients in
+    q, k and v match the full-softmax reference, taken in f32 on the
+    same (bf16-rounded) inputs."""
+    q, k, v, w = _qkv(jax.random.PRNGKey(sq * 31 + sk), b, sq, sk, h, hkv,
+                      d, dv, dtype)
+
+    def loss(impl):
+        def f(q, k, v):
+            o = ops.flash_attention(q, k, v, causal=causal, window=window,
+                                    impl=impl)
+            return jnp.sum(o.astype(jnp.float32) * w), o
+        return jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)
+
+    (_, out), grads = loss("xla_chunked")(q, k, v)
+    (_, exp), exp_grads = loss("xla")(*(x.astype(jnp.float32)
+                                        for x in (q, k, v)))
+    assert out.dtype == dtype
+    assert [g.dtype for g in grads] == [dtype] * 3
+    # bf16: the output and each gradient are rounded once to bf16 (2^-8
+    # relative), and p and ds enter the MXU in bf16
+    tol = 3e-5 if dtype == jnp.float32 else 2e-2
+    for got, want in zip((out,) + grads, (exp,) + exp_grads):
+        want = np.asarray(want, np.float32)
+        np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                                   rtol=tol,
+                                   atol=tol * max(1.0, np.abs(want).max()))
+
+
+def test_flash_one_block_empty_rows_are_zero():
+    """A query row with no visible key (causal, sq > sk) returns zeros,
+    not an average over the masked keys, and has finite gradients."""
+    q, k, v, _ = _qkv(jax.random.PRNGKey(4), 1, 12, 5, 2, 2, 16, 16,
+                      jnp.float32)
+
+    def f(q, k, v):
+        return ops.flash_attention(q, k, v, causal=True, impl="xla_chunked")
+
+    out = f(q, k, v)
+    np.testing.assert_array_equal(out[:, :7], 0.0)
+    assert np.all(np.abs(np.asarray(out[:, 7:])) > 0)
+    grads = jax.grad(lambda *a: jnp.sum(f(*a) ** 2), argnums=(0, 1, 2))(
+        q, k, v)
+    assert all(np.all(np.isfinite(g)) for g in grads)
+    np.testing.assert_array_equal(grads[0][:, :7], 0.0)
+
+
+@pytest.mark.parametrize("sk,block_k,path", [
+    (16, None, hooks.ONE_BLOCK), (16, 16, hooks.ONE_BLOCK),
+    (17, 16, hooks.KV_SCAN), (40, 8, hooks.KV_SCAN)])
+def test_flash_chunked_path_follows_kv_length(sk, block_k, path):
+    """The one-block path runs exactly where the kv fits one block
+    (``sk <= block_k``); longer kv runs the online-softmax scan."""
+    q, k, v, _ = _qkv(jax.random.PRNGKey(0), 1, 8, sk, 2, 2, 16, 16,
+                      jnp.float32)
+    fn = jax.jit(lambda q, k, v: ops.flash_attention(
+        q, k, v, causal=True, impl="xla_chunked", block_k=block_k))
+    names = set(re.findall(r'op_name="([^"]*)"',
+                           fn.lower(q, k, v).compile().as_text()))
+    other = hooks.KV_SCAN if path == hooks.ONE_BLOCK else hooks.ONE_BLOCK
+    assert any(path in n for n in names)
+    assert not any(other in n for n in names)
+    np.testing.assert_allclose(fn(q, k, v), ops.flash_attention(
+        q, k, v, causal=True, impl="xla"), rtol=3e-5, atol=3e-5)
 
 
 def test_flash_bf16():

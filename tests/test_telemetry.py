@@ -325,6 +325,24 @@ def test_layer_scope_in_compiled_step(step_hlo_paths, scope):
     assert not any(scope in s for s in others)   # no name inside another
 
 
+@pytest.mark.parametrize("scope", [hooks.SELF_ATTN, hooks.CROSS_ATTN])
+def test_one_block_backward_in_attention_scope(step_hlo_paths, scope):
+    """The one-block attention's recomputing backward (a custom_vjp, so
+    not JAX's own transpose) keeps its layer's scope: the recomputed
+    scores and the dq, dk and dv matmuls are counted in
+    ``attention_ms``, and ``attention/one_block`` tells its share."""
+    dots = [p for p in step_hlo_paths if hooks.in_scope(p, hooks.ONE_BLOCK)
+            and p.endswith("dot_general")]
+    assert all(hooks.in_scope(p, hooks.SELF_ATTN)
+               or hooks.in_scope(p, hooks.CROSS_ATTN) for p in dots), dots
+    mine = {p.rsplit("/", 2)[-2] for p in dots if hooks.in_scope(p, scope)
+            and "transpose(" in p}
+    # the backward's recomputed QK^T and dp = do V^T, dq, then dk and dv
+    assert mine == {"bqhd,bkhd->bhqk", "bhqk,bkhd->bqhd",
+                    "bhqk,bqhd->bkhd"}, mine
+    assert not any(hooks.in_scope(p, hooks.KV_SCAN) for p in step_hlo_paths)
+
+
 def test_step_has_no_host_callback():
     """With no profile directory (the default), the lowered step holds
     no host callback: spans and scopes add nothing to the program."""

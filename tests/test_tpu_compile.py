@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels import ops
 from repro.kernels.densify import densify_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.quantize import quantize_pallas
@@ -24,7 +25,8 @@ from repro.kernels.ssd import ssd_pallas
 # transformer-big: 16 x 256 tokens per chip, 16 heads of 64, vocab 33708
 # padded by ops.densify to its 512-row tile (34304), d_model 1024
 TOKENS, VOCAB_PADDED, D_MODEL = 4096, 34304, 1024
-BH, SEQ, HEAD_DIM = 16 * 16, 256, 64
+BATCH, HEADS, SEQ, HEAD_DIM, FRAMES = 16, 16, 256, 64, 256
+BH = BATCH * HEADS
 
 
 @pytest.fixture(scope="module")
@@ -95,3 +97,30 @@ def test_ssd_compiles_for_v5e(one_chip):
         jax.ShapeDtypeStruct((bh,), f32, sharding=one_chip),
         jax.ShapeDtypeStruct((bh, s, n), f32, sharding=one_chip),
         jax.ShapeDtypeStruct((bh, s, n), f32, sharding=one_chip))
+
+
+def test_one_block_attention_moves_fewer_bytes_for_v5e(one_chip):
+    """The gradient of one causal self-attention and one cross-attention
+    call at transformer-big shapes compiles for v5e on the one-block
+    path, and moves fewer bytes (XLA's cost analysis) than the same
+    calls forced onto the kv scan.  Relative, so no libtpu version's
+    absolute count is pinned."""
+    def grad_bytes(block_k):
+        def loss(q, k, v, kx, vx):
+            a = ops.flash_attention(q, k, v, causal=True,
+                                    impl="xla_chunked", block_k=block_k)
+            c = ops.flash_attention(a, kx, vx, causal=False,
+                                    impl="xla_chunked", block_k=block_k)
+            return jnp.sum(c.astype(jnp.float32))
+
+        x = jax.ShapeDtypeStruct((BATCH, SEQ, HEADS, HEAD_DIM),
+                                 jnp.bfloat16, sharding=one_chip)
+        xf = jax.ShapeDtypeStruct((BATCH, FRAMES, HEADS, HEAD_DIM),
+                                  jnp.bfloat16, sharding=one_chip)
+        compiled = jax.jit(jax.grad(loss, argnums=range(5))).lower(
+            x, x, x, xf, xf).compile()
+        cost = compiled.cost_analysis()
+        cost = cost[0] if isinstance(cost, list) else cost
+        return cost["bytes accessed"]
+
+    assert grad_bytes(None) < grad_bytes(128)
